@@ -303,8 +303,10 @@ def fused_dispatch_section(rng):
     llama3-e8t2 nominal shape and counts only the buffers the fusion
     removes: the permuted (N_pad, D) input and the (N_pad, D) expert
     output, each written once and read once in bf16, vs the fused path's
-    (k*T+1, D) bf16 slot partials plus the int32/f32 scalar-prefetch
-    vectors. Asserted: fused traffic strictly below unfused."""
+    (k*T, D) bf16 slot partials, the pass that packs x's bf16 rows into
+    32-bit words for the row DMAs (one read, one write of (T, D) bf16), and
+    the int32/f32 scalar-prefetch vectors. Asserted: fused traffic strictly
+    below unfused."""
     from repro.kernels.expert_gemm import _aligned_rows, _fused_unfused_ref
     from repro.kernels.ops import grouped_gemm_fused
 
@@ -331,9 +333,10 @@ def fused_dispatch_section(rng):
     Nn_pad = _aligned_rows(Nn, E, bc)
     # unfused: xs scatter-write + kernel read, ys kernel-write + gather read
     unfused_bytes = 2 * 2 * Nn_pad * D * 2
-    # fused: slot partials (k*T+1, D) bf16 write + read, plus the
-    # tok_pad/row_out (int32) and gate_pad (f32) prefetch vectors
-    fused_bytes = 2 * (k * Tn + 1) * D * 2 * 2 + 2 * 3 * Nn_pad * 4
+    # fused: slot partials (k*T, D) bf16 write + read, the x word-packing
+    # pass, and the tok_pad/row_out (int32) and gate_pad (f32) vectors
+    fused_bytes = (2 * k * Tn * D * 2 * 2 + 2 * Tn * D * 2
+                   + 2 * 3 * Nn_pad * 4)
     assert fused_bytes < unfused_bytes, (
         f"fused dispatch traffic {fused_bytes} not below unfused "
         f"{unfused_bytes}"

@@ -459,7 +459,12 @@ def scaling_worker(dp: int, ep: int) -> None:
 def run_scaling():
     """Launch one subprocess per topology and build the ``scaling`` report
     section (the parent process has already initialized jax at one device,
-    so fake-device runs must be fresh processes)."""
+    so fake-device runs must be fresh processes).
+
+    The children start after the parent has touched JAX. That is safe only
+    because each child is pinned to the CPU backend (``JAX_PLATFORMS=cpu``
+    below): on a machine with a TPU the parent holds the chip, and a child
+    that reached for it would fail or hang. Keep the pin."""
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     rows = []
     for devices, dp, ep in SCALE_TOPOLOGIES:

@@ -28,7 +28,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core.dispatch.base import (
@@ -105,14 +104,14 @@ class AllToAllDispatcher(TokenDispatcher):
                 use_kernel=use_kernel,
             )
 
-        fn = shard_map(
+        fn = jax.shard_map(
             local_moe,
             mesh=mesh,
             in_specs=(
                 P(token_axes, None), P(token_axes, None), P(token_axes, None), w_specs,
             ),
             out_specs=P(token_axes, None),
-            check_rep=False,
+            check_vma=False,
         )
         return fn(x, gates, idx, experts)
 

@@ -15,8 +15,8 @@ so `FoldingPlan`/EP sharding splits them alongside their experts
 (``quantize_decls``).
 
 KV pages (``quantize_kv``): per-written-token, per-kv-head symmetric
-scales stored in a sidecar pool leaf shaped ``(periods, num_pages,
-page_size, KV, 1)``. Page-granular scales cannot survive incremental
+scales stored in a sidecar pool leaf shaped ``(periods, num_pages, KV,
+page_size, 1)``. Page-granular scales cannot survive incremental
 decode writes (a later token cannot retroactively rescale the page), so
 the sidecar is indexed exactly like the page payload and rides every
 pool-tree operation (COW ``copy_pages``, defrag ``permute_pool``, DP

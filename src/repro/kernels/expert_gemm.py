@@ -640,6 +640,14 @@ def grouped_gemm(
 # ---------------------------------------------------------------------------
 
 
+def _scale_rows(*scales):
+    """(E, N) per-channel scales -> (E, 1, N): a scale tile is then a
+    ``(1, bn)`` block whose second-minor dim spans the whole (unit) axis,
+    which the TPU's (8, 128) block tiling accepts; a ``(1, bn)`` row slice
+    of the 2-D array is refused."""
+    return tuple(s.reshape(s.shape[0], 1, s.shape[1]) for s in scales)
+
+
 def _gate_up_kernel_q8(
     x_ref, wg_ref, wu_ref, sg_ref, su_ref, h_ref, g_acc, u_acc, *, nd: int
 ):
@@ -690,6 +698,7 @@ def _expert_fwd_q8_impl(
 ) -> jax.Array:
     E, C, D = xe.shape
     F = w_gate.shape[-1]
+    s_gate, s_up, s_down = _scale_rows(s_gate, s_up, s_down)
     bc = _pick(blocks[0], C, align=8)
     # output tiles size the fp32 accumulators -> bf16-equivalent budget;
     # int8 contraction tiles stream 2x the rows at the same byte budget
@@ -705,8 +714,8 @@ def _expert_fwd_q8_impl(
             pl.BlockSpec((1, bc, bd_c), lambda e, c, f, d: (e, c, d)),
             pl.BlockSpec((1, bd_c, bf_o), lambda e, c, f, d: (e, d, f)),
             pl.BlockSpec((1, bd_c, bf_o), lambda e, c, f, d: (e, d, f)),
-            pl.BlockSpec((1, bf_o), lambda e, c, f, d: (e, f)),
-            pl.BlockSpec((1, bf_o), lambda e, c, f, d: (e, f)),
+            pl.BlockSpec((1, 1, bf_o), lambda e, c, f, d: (e, 0, f)),
+            pl.BlockSpec((1, 1, bf_o), lambda e, c, f, d: (e, 0, f)),
         ],
         out_specs=pl.BlockSpec((1, bc, bf_o), lambda e, c, f, d: (e, c, f)),
         out_shape=jax.ShapeDtypeStruct((E, C, F), xe.dtype),
@@ -723,7 +732,7 @@ def _expert_fwd_q8_impl(
         in_specs=[
             pl.BlockSpec((1, bc, bf_c), lambda e, c, d, f: (e, c, f)),
             pl.BlockSpec((1, bf_c, bd_o), lambda e, c, d, f: (e, f, d)),
-            pl.BlockSpec((1, bd_o), lambda e, c, d, f: (e, d)),
+            pl.BlockSpec((1, 1, bd_o), lambda e, c, d, f: (e, 0, d)),
         ],
         out_specs=pl.BlockSpec((1, bc, bd_o), lambda e, c, d, f: (e, c, d)),
         out_shape=jax.ShapeDtypeStruct((E, C, D), xe.dtype),
@@ -816,6 +825,7 @@ def _grouped_fwd_q8_impl(
 ) -> jax.Array:
     N_pad, D = xs.shape
     E, _, F = w_gate.shape
+    s_gate, s_up, s_down = _scale_rows(s_gate, s_up, s_down)
     bc = blocks[0]
     assert N_pad % bc == 0, (N_pad, bc)
     bf_o, bd_o = _pick(blocks[1], F), _pick(blocks[2], D)
@@ -835,8 +845,8 @@ def _grouped_fwd_q8_impl(
                 pl.BlockSpec((bc, bd_c), lambda t, f, d, tg, tr: (t, d)),
                 pl.BlockSpec((1, bd_c, bf_o), lambda t, f, d, tg, tr: (tg[t], d, f)),
                 pl.BlockSpec((1, bd_c, bf_o), lambda t, f, d, tg, tr: (tg[t], d, f)),
-                pl.BlockSpec((1, bf_o), lambda t, f, d, tg, tr: (tg[t], f)),
-                pl.BlockSpec((1, bf_o), lambda t, f, d, tg, tr: (tg[t], f)),
+                pl.BlockSpec((1, 1, bf_o), lambda t, f, d, tg, tr: (tg[t], 0, f)),
+                pl.BlockSpec((1, 1, bf_o), lambda t, f, d, tg, tr: (tg[t], 0, f)),
             ],
             out_specs=pl.BlockSpec((bc, bf_o), lambda t, f, d, tg, tr: (t, f)),
             scratch_shapes=[
@@ -856,7 +866,7 @@ def _grouped_fwd_q8_impl(
             in_specs=[
                 pl.BlockSpec((bc, bf_c), lambda t, d, f, tg, tr: (t, f)),
                 pl.BlockSpec((1, bf_c, bd_o), lambda t, d, f, tg, tr: (tg[t], f, d)),
-                pl.BlockSpec((1, bd_o), lambda t, d, f, tg, tr: (tg[t], d)),
+                pl.BlockSpec((1, 1, bd_o), lambda t, d, f, tg, tr: (tg[t], 0, d)),
             ],
             out_specs=pl.BlockSpec((bc, bd_o), lambda t, d, f, tg, tr: (t, d)),
             scratch_shapes=[pltpu.VMEM((bc, bd_o), jnp.float32)],
@@ -895,20 +905,30 @@ def grouped_gemm_q8(
 # it. The fused kernels absorb both, the same scalar-prefetch block-table
 # trick as paged_attention:
 #
-# * prologue gather: the per-row token ids (``tok``) are scalar-prefetched
-#   and resolved in the x BlockSpec index map — an extra innermost grid dim
-#   ``r`` stages one (1, bd) row of the token-major x per step into a
-#   (bc, bd) VMEM scratch, and the gate/up dot fires once per (t, f, d) at
-#   r == bc-1. HBM read traffic equals the unfused kernel's reads of the
-#   materialized buffer; the buffer's write+read round trip disappears.
-# * epilogue combine: the down kernel writes each row gate-weighted (fp32
-#   multiply) straight to a slot-partials output shaped (k*T + 1, D) at
+# * prologue gather: the per-row token ids (``tok``) are scalar-prefetched.
+#   At the first (f, d) step of row tile t the kernel DMAs the tile's valid
+#   rows x[tok[t*bc + r]] of the token-major x (left in HBM, ``pl.ANY``)
+#   into a VMEM row buffer, one row copy each, all in flight at once on one
+#   DMA semaphore; every (f, d) step of the tile then reads its (bc, bd)
+#   slice from VMEM. Each x row is read from HBM once per tile (the unfused
+#   kernel re-reads its materialized buffer once per F tile), and the
+#   buffer's write+read round trip disappears.
+# * epilogue combine: at the last F step the down kernel scales its fp32
+#   accumulator by the per-row gate, rounds to the activation dtype, and
+#   DMAs each valid row straight to a slot-partials output (k*T, D) at
 #   scalar-prefetched ``row_out`` = slot*T + token. Each (token, slot) pair
 #   is unique in the top-k assignment list, so every partials row is
-#   written exactly once — a race-free scatter with no atomics; padding
-#   rows and non-final-f grid steps land on the trash row k*T. The k slot
+#   written exactly once — a race-free scatter with no atomics. The k slot
 #   planes are summed in fp32 outside the kernel (the per-token k-way
 #   combine), matching the fp32-accum convention.
+# * one row per DMA: the TPU tiles the last two dims of every buffer, and
+#   packs two bf16 rows into one 32-bit sublane, so a single bf16 row
+#   cannot be copied. Rows travel as (rows, 1, words) of 32-bit words: f32
+#   as is, and 16-bit dtypes as pairs — within each bd-wide column block,
+#   column j in the low half-word and column j + bd/2 in the high one
+#   (``_pack_rows``). The kernels split the contraction into the two
+#   halves, so no lane shuffle is needed, and HBM traffic stays that of
+#   the 16-bit data.
 #
 # Backward: custom_vjp with inputs-only residuals. The cotangent is pulled
 # through ``jax.vjp`` of the UNFUSED composition (scatter -> grouped_gemm,
@@ -917,122 +937,148 @@ def grouped_gemm_q8(
 # nothing O(N*F) — and no (N_pad, D) buffer — is saved across fwd/bwd.
 # ---------------------------------------------------------------------------
 
-_TRASH = -1  # sentinel resolved to the k*T trash row at call sites
+def _lo(w):
+    """Low half-words of int32 ``w`` as the f32 whose top bits they are
+    (exact for bf16)."""
+    return jax.lax.bitcast_convert_type(jax.lax.shift_left(w, 16), jnp.float32)
+
+
+def _hi(w):
+    return jax.lax.bitcast_convert_type(w & jnp.int32(-65536), jnp.float32)
+
+
+def _pack(lo, hi, dtype):
+    """Round f32 ``lo``/``hi`` to the 16-bit ``dtype`` and pack them into
+    int32 words (``lo`` in the low half)."""
+    bits = lambda a: jax.lax.bitcast_convert_type(  # noqa: E731
+        a.astype(dtype).astype(jnp.float32), jnp.int32)
+    return jax.lax.shift_right_logical(bits(lo), 16) | (bits(hi) & jnp.int32(-65536))
+
+
+def _pack_rows(a, bd: int):
+    """(R, D) 16-bit -> (R, 1, D // 2) int32 words, per bd-wide column
+    block: column j in the low half-word, column j + bd/2 in the high."""
+    R, D = a.shape
+    b = jax.lax.bitcast_convert_type(a, jnp.uint16).astype(jnp.int32)
+    b = b.reshape(R, D // bd, 2, bd // 2)
+    return (b[:, :, 0] | jax.lax.shift_left(b[:, :, 1], 16)).reshape(R, 1, D // 2)
+
+
+def _unpack_rows(w, bd: int):
+    """Inverse of :func:`_pack_rows`, as f32 (R, D)."""
+    R = w.shape[0]
+    w = w.reshape(R, -1, 1, bd // 2)
+    return jnp.concatenate([_lo(w), _hi(w)], axis=2).reshape(R, -1)
+
+
+def _row_copies(n, copy):
+    """Start ``copy(r)`` for r < n on one DMA semaphore, then wait for all
+    of them (every copy moves one row, so each wait retires one)."""
+    def start(r, c):
+        copy(r).start()
+        return c
+
+    def wait(r, c):
+        copy(0).wait()
+        return c
+
+    jax.lax.fori_loop(0, n, start, 0)
+    jax.lax.fori_loop(0, n, wait, 0)
 
 
 def _fused_gate_up_kernel(
-    tg_ref, tr_ref, tok_ref, x_ref, wg_ref, wu_ref, h_ref, x_scr, g_acc, u_acc,
-    *, nd: int, bc: int, bf: int,
+    tg_ref, tr_ref, tok_ref, x_hbm, wg_ref, wu_ref, *rest,
+    nd: int, bc: int, bf: int, bd: int, quant: bool, packed: bool,
 ):
-    """Gather prologue + gate/up: grid (nt, nf, nd, bc). Each r-step DMAs
-    row tok[t*bc + r] of the token-major x (resolved in the BlockSpec index
-    map) into the staging scratch; the MXU work runs once per (t, f, d)."""
-    t, d, r = pl.program_id(0), pl.program_id(2), pl.program_id(3)
-    x_scr[pl.ds(r, 1), :] = x_ref[0][None]
-    last = r == bc - 1
+    """Gather prologue + gate/up: grid (nt, nf, nd). At (f, d) == (0, 0)
+    the tile's valid rows are DMA'd from the token-major x in HBM into
+    ``x_scr``; the MXU work per (t, f, d) reads its column block from VMEM
+    (as its two packed halves when ``packed``)."""
+    if quant:
+        sg_ref, su_ref, h_ref, x_scr, g_acc, u_acc, sem = rest
+    else:
+        h_ref, x_scr, g_acc, u_acc, sem = rest
+    t, f, d = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    valid = tr_ref[t]
 
-    @pl.when(jnp.logical_and(last, d == 0))
+    @pl.when(jnp.logical_and(f == 0, d == 0))
+    def _gather():
+        _row_copies(valid, lambda r: pltpu.make_async_copy(
+            x_hbm.at[tok_ref[t * bc + r]], x_scr.at[r], sem
+        ))
+
+    @pl.when(d == 0)
     def _init():
         g_acc[...] = jnp.zeros_like(g_acc)
         u_acc[...] = jnp.zeros_like(u_acc)
 
-    valid = tr_ref[t]
-
-    @pl.when(jnp.logical_and(last, valid > 0))
+    @pl.when(valid > 0)
     def _compute():
-        x = x_scr[...]
-        g_acc[...] += jnp.dot(x, wg_ref[0], preferred_element_type=jnp.float32)
-        u_acc[...] += jnp.dot(x, wu_ref[0], preferred_element_type=jnp.float32)
+        if packed:
+            h2 = bd // 2
+            w = x_scr[:, 0, pl.ds(pl.multiple_of(d * h2, h2), h2)]
+            parts = ((_lo(w), slice(0, h2)), (_hi(w), slice(h2, bd)))
+        else:
+            parts = ((x_scr[:, 0, pl.ds(pl.multiple_of(d * bd, bd), bd)],
+                      slice(0, bd)),)
+        for x, rows in parts:
+            x = x.astype(h_ref.dtype)
+            g_acc[...] += jnp.dot(x, wg_ref[0, rows].astype(x.dtype),
+                                  preferred_element_type=jnp.float32)
+            u_acc[...] += jnp.dot(x, wu_ref[0, rows].astype(x.dtype),
+                                  preferred_element_type=jnp.float32)
 
-    @pl.when(jnp.logical_and(last, d == nd - 1))
+    @pl.when(d == nd - 1)
     def _epilogue():
+        g, u = g_acc[...], u_acc[...]
+        if quant:
+            g = g * sg_ref[0].astype(jnp.float32)
+            u = u * su_ref[0].astype(jnp.float32)
         rows = jax.lax.broadcasted_iota(jnp.int32, (bc, bf), 0)
-        h = _silu(g_acc[...]) * u_acc[...]
-        h_ref[...] = jnp.where(rows < valid, h, 0.0).astype(h_ref.dtype)
+        h_ref[...] = jnp.where(rows < valid, _silu(g) * u, 0.0).astype(h_ref.dtype)
 
 
 def _fused_down_kernel(
-    tg_ref, tr_ref, row_ref, gate_ref, h_ref, wd_ref, o_ref, acc,
-    *, nf: int, bc: int,
+    tg_ref, tr_ref, row_ref, h_ref, wd_ref, gate_ref, *rest,
+    nf: int, bc: int, bd: int, quant: bool, packed,
 ):
-    """Down GEMM + combine epilogue: grid (nt, nd, nf, bc). The F
-    contraction accumulates once per (t, d, f) at r == 0; at f == nf-1
-    every r-step emits one gate-weighted row to its slot-partials slot
-    (the out BlockSpec routes non-final-f steps and padding rows to the
-    trash row)."""
-    t, f, r = pl.program_id(0), pl.program_id(2), pl.program_id(3)
+    """Down GEMM + combine epilogue: grid (nt, nd, nf). The F contraction
+    accumulates in fp32; at f == nf-1 the gate-weighted (bc, bd) tile is
+    staged in VMEM (packed into 32-bit words of the 16-bit ``packed``
+    dtype, if given) and each valid row is DMA'd to its slot-partials row."""
+    if quant:
+        sd_ref, o_hbm, acc, y_scr, sem = rest
+    else:
+        o_hbm, acc, y_scr, sem = rest
+    t, d, f = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    valid = tr_ref[t]
 
-    @pl.when(jnp.logical_and(r == 0, f == 0))
+    @pl.when(f == 0)
     def _init():
         acc[...] = jnp.zeros_like(acc)
 
-    valid = tr_ref[t]
-
-    @pl.when(jnp.logical_and(r == 0, valid > 0))
-    def _compute():
-        acc[...] += jnp.dot(h_ref[...], wd_ref[0], preferred_element_type=jnp.float32)
-
-    @pl.when(f == nf - 1)
-    def _write():
-        g = gate_ref[t * bc + r]  # f32 scalar from SMEM
-        row = acc[pl.ds(r, 1), :][0] * g
-        o_ref[0] = jnp.where(r < valid, row, 0.0).astype(o_ref.dtype)
-
-
-def _fused_down_kernel_q8(
-    tg_ref, tr_ref, row_ref, gate_ref, h_ref, wd_ref, sd_ref, o_ref, acc,
-    *, nf: int, bc: int,
-):
-    t, f, r = pl.program_id(0), pl.program_id(2), pl.program_id(3)
-
-    @pl.when(jnp.logical_and(r == 0, f == 0))
-    def _init():
-        acc[...] = jnp.zeros_like(acc)
-
-    valid = tr_ref[t]
-
-    @pl.when(jnp.logical_and(r == 0, valid > 0))
+    @pl.when(valid > 0)
     def _compute():
         acc[...] += jnp.dot(
             h_ref[...], wd_ref[0].astype(h_ref.dtype),
             preferred_element_type=jnp.float32,
         )
 
-    @pl.when(f == nf - 1)
-    def _write():
-        g = gate_ref[t * bc + r]
-        row = acc[pl.ds(r, 1), :][0] * sd_ref[0].astype(jnp.float32) * g
-        o_ref[0] = jnp.where(r < valid, row, 0.0).astype(o_ref.dtype)
-
-
-def _fused_gate_up_kernel_q8(
-    tg_ref, tr_ref, tok_ref, x_ref, wg_ref, wu_ref, sg_ref, su_ref, h_ref,
-    x_scr, g_acc, u_acc, *, nd: int, bc: int, bf: int,
-):
-    t, d, r = pl.program_id(0), pl.program_id(2), pl.program_id(3)
-    x_scr[pl.ds(r, 1), :] = x_ref[0][None]
-    last = r == bc - 1
-
-    @pl.when(jnp.logical_and(last, d == 0))
-    def _init():
-        g_acc[...] = jnp.zeros_like(g_acc)
-        u_acc[...] = jnp.zeros_like(u_acc)
-
-    valid = tr_ref[t]
-
-    @pl.when(jnp.logical_and(last, valid > 0))
-    def _compute():
-        x = x_scr[...]
-        g_acc[...] += jnp.dot(x, wg_ref[0].astype(x.dtype), preferred_element_type=jnp.float32)
-        u_acc[...] += jnp.dot(x, wu_ref[0].astype(x.dtype), preferred_element_type=jnp.float32)
-
-    @pl.when(jnp.logical_and(last, d == nd - 1))
-    def _epilogue():
-        rows = jax.lax.broadcasted_iota(jnp.int32, (bc, bf), 0)
-        g = g_acc[...] * sg_ref[0].astype(jnp.float32)
-        u = u_acc[...] * su_ref[0].astype(jnp.float32)
-        h = _silu(g) * u
-        h_ref[...] = jnp.where(rows < valid, h, 0.0).astype(h_ref.dtype)
+    @pl.when(jnp.logical_and(f == nf - 1, valid > 0))
+    def _scatter():
+        y = acc[...] * gate_ref[...]  # (bc, 1) f32 gate per row
+        if quant:
+            y = y * sd_ref[0].astype(jnp.float32)
+        if packed is not None:
+            h2 = bd // 2
+            y_scr[:, 0, :] = _pack(y[:, :h2], y[:, h2:], packed)
+            col = pl.ds(pl.multiple_of(d * h2, h2), h2)
+        else:
+            y_scr[:, 0, :] = y
+            col = pl.ds(pl.multiple_of(d * bd, bd), bd)
+        _row_copies(valid, lambda r: pltpu.make_async_copy(
+            y_scr.at[r], o_hbm.at[row_ref[t * bc + r], :, col], sem
+        ))
 
 
 def _aligned_rows(N: int, E: int, row_block: int) -> int:
@@ -1045,16 +1091,14 @@ def _aligned_rows(N: int, E: int, row_block: int) -> int:
 
 
 def _fused_prefetch(token, dest, slot, gate_sorted, T, N_pad):
-    """Scalar-prefetch vectors indexed by buffer row: source token id
-    (padding rows -> 0, masked by tr), slot-partials destination row
-    (padding rows -> the k*T trash row), and f32 gate per row."""
-    N = token.shape[0]
-    k = N // T
+    """Per-buffer-row vectors: source token id and slot-partials
+    destination row (scalar-prefetched; padding rows are never copied) and
+    the f32 gate as a (N_pad, 1) column (padding rows -> 0)."""
     tok_pad = jnp.zeros((N_pad,), jnp.int32).at[dest].set(token.astype(jnp.int32))
-    row_out = jnp.full((N_pad,), k * T, jnp.int32).at[dest].set(
+    row_out = jnp.zeros((N_pad,), jnp.int32).at[dest].set(
         slot.astype(jnp.int32) * T + token.astype(jnp.int32)
     )
-    gate_pad = jnp.zeros((N_pad,), jnp.float32).at[dest].set(
+    gate_pad = jnp.zeros((N_pad, 1), jnp.float32).at[dest, 0].set(
         gate_sorted.astype(jnp.float32)
     )
     return tok_pad, row_out, gate_pad
@@ -1062,9 +1106,10 @@ def _fused_prefetch(token, dest, slot, gate_sorted, T, N_pad):
 
 def _fused_fwd_impl(
     x: jax.Array,  # (T, D) token-major model activations
-    w_gate: jax.Array,  # (E, D, F)
+    w_gate: jax.Array,  # (E, D, F) (int8 when ``scales`` is given)
     w_up: jax.Array,  # (E, D, F)
     w_down: jax.Array,  # (E, F, D)
+    scales,  # None, or (s_gate (E, F), s_up (E, F), s_down (E, D))
     group_sizes: jax.Array,  # (E,)
     token: jax.Array,  # (N,) source token per sorted row
     dest: jax.Array,  # (N,) buffer row per sorted assignment
@@ -1078,62 +1123,83 @@ def _fused_fwd_impl(
     N = token.shape[0]
     assert N % T == 0, (N, T)
     k = N // T
+    quant = scales is not None
     bc = blocks[0]
     N_pad = _aligned_rows(N, E, bc)
-    bf, bd = (_pick(b, d) for b, d in zip(blocks[1:], (F, D)))
-    nt, nf, nd = N_pad // bc, F // bf, D // bd
+    # output tiles size the fp32 accumulators; int8 contraction tiles
+    # stream twice the rows at the same byte budget (as grouped_gemm_q8)
+    bf_o, bd_o = _pick(blocks[1], F), _pick(blocks[2], D)
+    bd_c = _pick(blocks[2], D, itemsize=1) if quant else bd_o
+    bf_c = _pick(blocks[1], F, itemsize=1) if quant else bf_o
+    nt = N_pad // bc
     tg, tr = group_tiling(group_sizes, nt, bc)
     tok_pad, row_out, gate_pad = _fused_prefetch(token, dest, slot, gate_sorted, T, N_pad)
+    s_gate, s_up, s_down = _scale_rows(*scales) if quant else (None,) * 3
+    # rows move as 32-bit words: 16-bit activations packed in pairs
+    packed = x.dtype.itemsize == 2
+    assert packed or x.dtype.itemsize == 4, x.dtype
+    wpe = 2 if packed else 1  # elements per 32-bit word
+    wdt = jnp.int32 if packed else x.dtype
+    rows = _pack_rows(x, bd_c) if packed else x.reshape(T, 1, D)
 
     h = pl.pallas_call(
-        functools.partial(_fused_gate_up_kernel, nd=nd, bc=bc, bf=bf),
+        functools.partial(
+            _fused_gate_up_kernel, nd=D // bd_c, bc=bc, bf=bf_o, bd=bd_c,
+            quant=quant, packed=packed,
+        ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            grid=(nt, nf, nd, bc),
+            grid=(nt, F // bf_o, D // bd_c),
             in_specs=[
-                pl.BlockSpec(
-                    (1, bd), lambda t, f, d, r, tg, tr, tok: (tok[t * bc + r], d)
-                ),
-                pl.BlockSpec((1, bd, bf), lambda t, f, d, r, tg, tr, tok: (tg[t], d, f)),
-                pl.BlockSpec((1, bd, bf), lambda t, f, d, r, tg, tr, tok: (tg[t], d, f)),
-            ],
-            out_specs=pl.BlockSpec((bc, bf), lambda t, f, d, r, tg, tr, tok: (t, f)),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec((1, bd_c, bf_o), lambda t, f, d, tg, tr, tok: (tg[t], d, f)),
+                pl.BlockSpec((1, bd_c, bf_o), lambda t, f, d, tg, tr, tok: (tg[t], d, f)),
+            ] + ([
+                pl.BlockSpec((1, 1, bf_o), lambda t, f, d, tg, tr, tok: (tg[t], 0, f)),
+                pl.BlockSpec((1, 1, bf_o), lambda t, f, d, tg, tr, tok: (tg[t], 0, f)),
+            ] if quant else []),
+            out_specs=pl.BlockSpec((bc, bf_o), lambda t, f, d, tg, tr, tok: (t, f)),
             scratch_shapes=[
-                pltpu.VMEM((bc, bd), x.dtype),
-                pltpu.VMEM((bc, bf), jnp.float32),
-                pltpu.VMEM((bc, bf), jnp.float32),
+                pltpu.VMEM((bc, 1, D // wpe), wdt),
+                pltpu.VMEM((bc, bf_o), jnp.float32),
+                pltpu.VMEM((bc, bf_o), jnp.float32),
+                pltpu.SemaphoreType.DMA,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((N_pad, F), x.dtype),
         interpret=interpret,
-    )(tg, tr, tok_pad, x, w_gate, w_up)
+    )(tg, tr, tok_pad, rows, w_gate, w_up, *((s_gate, s_up) if quant else ()))
 
-    trash = k * T
     partials = pl.pallas_call(
-        functools.partial(_fused_down_kernel, nf=nf, bc=bc),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
-            grid=(nt, nd, nf, bc),
-            in_specs=[
-                pl.BlockSpec((bc, bf), lambda t, d, f, r, tg, tr, ro, ga: (t, f)),
-                pl.BlockSpec(
-                    (1, bf, bd), lambda t, d, f, r, tg, tr, ro, ga: (tg[t], f, d)
-                ),
-            ],
-            out_specs=pl.BlockSpec(
-                (1, bd),
-                lambda t, d, f, r, tg, tr, ro, ga: (
-                    jnp.where(f == nf - 1, ro[t * bc + r], trash), d
-                ),
-            ),
-            scratch_shapes=[pltpu.VMEM((bc, bd), jnp.float32)],
+        functools.partial(
+            _fused_down_kernel, nf=F // bf_c, bc=bc, bd=bd_o, quant=quant,
+            packed=x.dtype if packed else None,
         ),
-        out_shape=jax.ShapeDtypeStruct((k * T + 1, D), x.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(nt, D // bd_o, F // bf_c),
+            in_specs=[
+                pl.BlockSpec((bc, bf_c), lambda t, d, f, tg, tr, ro: (t, f)),
+                pl.BlockSpec((1, bf_c, bd_o), lambda t, d, f, tg, tr, ro: (tg[t], f, d)),
+                pl.BlockSpec((bc, 1), lambda t, d, f, tg, tr, ro: (t, 0)),
+            ] + ([
+                pl.BlockSpec((1, 1, bd_o), lambda t, d, f, tg, tr, ro: (tg[t], 0, d)),
+            ] if quant else []),
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
+            scratch_shapes=[
+                pltpu.VMEM((bc, bd_o), jnp.float32),
+                pltpu.VMEM((bc, 1, bd_o // wpe), wdt),
+                pltpu.SemaphoreType.DMA,
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((k * T, 1, D // wpe), wdt),
         interpret=interpret,
-    )(tg, tr, row_out, gate_pad, h, w_down)
+    )(tg, tr, row_out, h, w_down, gate_pad, *((s_down,) if quant else ()))
 
     # k-way per-token combine: fp32 sum over the slot planes, cast once
-    y = jnp.sum(partials[: k * T].reshape(k, T, D).astype(jnp.float32), axis=0)
+    partials = (_unpack_rows(partials, bd_o) if packed
+                else partials.reshape(k * T, D).astype(jnp.float32))
+    y = jnp.sum(partials.reshape(k, T, D), axis=0)
     return y.astype(x.dtype)
 
 
@@ -1161,8 +1227,8 @@ def _fused_moe_p(
     blocks, interpret,
 ):
     return _fused_fwd_impl(
-        x, w_gate, w_up, w_down, group_sizes, token, dest, slot, gate_sorted,
-        blocks, interpret,
+        x, w_gate, w_up, w_down, None, group_sizes, token, dest, slot,
+        gate_sorted, blocks, interpret,
     )
 
 
@@ -1171,8 +1237,8 @@ def _fused_moe_fwd(
     blocks, interpret,
 ):
     y = _fused_fwd_impl(
-        x, w_gate, w_up, w_down, group_sizes, token, dest, slot, gate_sorted,
-        blocks, interpret,
+        x, w_gate, w_up, w_down, None, group_sizes, token, dest, slot,
+        gate_sorted, blocks, interpret,
     )
     # inputs-only residuals: no (N_pad, D) buffer, no (N, F) intermediate
     return y, (x, w_gate, w_up, w_down, group_sizes, token, dest, slot, gate_sorted)
@@ -1230,81 +1296,6 @@ def grouped_gemm_fused(
     )
 
 
-def _fused_fwd_q8_impl(
-    x, w_gate, w_up, w_down, s_gate, s_up, s_down, group_sizes,
-    token, dest, slot, gate_sorted, blocks, interpret,
-):
-    T, D = x.shape
-    E, _, F = w_gate.shape
-    N = token.shape[0]
-    assert N % T == 0, (N, T)
-    k = N // T
-    bc = blocks[0]
-    N_pad = _aligned_rows(N, E, bc)
-    bf_o, bd_o = _pick(blocks[1], F), _pick(blocks[2], D)
-    bd_c = _pick(blocks[2], D, itemsize=1)
-    bf_c = _pick(blocks[1], F, itemsize=1)
-    nt = N_pad // bc
-    tg, tr = group_tiling(group_sizes, nt, bc)
-    tok_pad, row_out, gate_pad = _fused_prefetch(token, dest, slot, gate_sorted, T, N_pad)
-
-    h = pl.pallas_call(
-        functools.partial(
-            _fused_gate_up_kernel_q8, nd=D // bd_c, bc=bc, bf=bf_o
-        ),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=(nt, F // bf_o, D // bd_c, bc),
-            in_specs=[
-                pl.BlockSpec(
-                    (1, bd_c), lambda t, f, d, r, tg, tr, tok: (tok[t * bc + r], d)
-                ),
-                pl.BlockSpec((1, bd_c, bf_o), lambda t, f, d, r, tg, tr, tok: (tg[t], d, f)),
-                pl.BlockSpec((1, bd_c, bf_o), lambda t, f, d, r, tg, tr, tok: (tg[t], d, f)),
-                pl.BlockSpec((1, bf_o), lambda t, f, d, r, tg, tr, tok: (tg[t], f)),
-                pl.BlockSpec((1, bf_o), lambda t, f, d, r, tg, tr, tok: (tg[t], f)),
-            ],
-            out_specs=pl.BlockSpec((bc, bf_o), lambda t, f, d, r, tg, tr, tok: (t, f)),
-            scratch_shapes=[
-                pltpu.VMEM((bc, bd_c), x.dtype),
-                pltpu.VMEM((bc, bf_o), jnp.float32),
-                pltpu.VMEM((bc, bf_o), jnp.float32),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((N_pad, F), x.dtype),
-        interpret=interpret,
-    )(tg, tr, tok_pad, x, w_gate, w_up, s_gate, s_up)
-
-    trash = k * T
-    nf_c = F // bf_c
-    partials = pl.pallas_call(
-        functools.partial(_fused_down_kernel_q8, nf=nf_c, bc=bc),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
-            grid=(nt, D // bd_o, nf_c, bc),
-            in_specs=[
-                pl.BlockSpec((bc, bf_c), lambda t, d, f, r, tg, tr, ro, ga: (t, f)),
-                pl.BlockSpec(
-                    (1, bf_c, bd_o), lambda t, d, f, r, tg, tr, ro, ga: (tg[t], f, d)
-                ),
-                pl.BlockSpec((1, bd_o), lambda t, d, f, r, tg, tr, ro, ga: (tg[t], d)),
-            ],
-            out_specs=pl.BlockSpec(
-                (1, bd_o),
-                lambda t, d, f, r, tg, tr, ro, ga: (
-                    jnp.where(f == nf_c - 1, ro[t * bc + r], trash), d
-                ),
-            ),
-            scratch_shapes=[pltpu.VMEM((bc, bd_o), jnp.float32)],
-        ),
-        out_shape=jax.ShapeDtypeStruct((k * T + 1, D), x.dtype),
-        interpret=interpret,
-    )(tg, tr, row_out, gate_pad, h, w_down, s_down)
-
-    y = jnp.sum(partials[: k * T].reshape(k, T, D).astype(jnp.float32), axis=0)
-    return y.astype(x.dtype)
-
-
 @functools.partial(jax.jit, static_argnames=("blocks", "interpret"))
 def grouped_gemm_fused_q8(
     x: jax.Array,  # (T, D) token-major activations (pre-dispatch)
@@ -1325,7 +1316,7 @@ def grouped_gemm_fused_q8(
     """int8-weight fused-dispatch sorted MoE FFN (serving): fused dequant,
     gather prologue, gate-weighted combine epilogue. Forward-only, like
     :func:`grouped_gemm_q8`."""
-    return _fused_fwd_q8_impl(
-        x, w_gate, w_up, w_down, s_gate, s_up, s_down, group_sizes,
+    return _fused_fwd_impl(
+        x, w_gate, w_up, w_down, (s_gate, s_up, s_down), group_sizes,
         token, dest, slot, gate_sorted, tuple(blocks), interpret,
     )

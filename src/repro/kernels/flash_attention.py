@@ -5,8 +5,11 @@ Forward: online-softmax over KV blocks with fp32 m/l/acc carried in VMEM
 scratch — the TPU-tiled version of the blockwise XLA path in
 models/attention.py. GQA reads the shared KV head via the BlockSpec index
 map (kv = h // group) instead of materializing a broadcast copy in HBM. The
-forward also emits the logsumexp (B*H, Sq) — the only extra residual the
-backward needs.
+forward also emits the logsumexp (B*H, Sq, 1) — the only extra residual
+the backward needs. Per-row softmax statistics (m, l, lse, delta) are
+``(rows, 1)`` columns throughout: the TPU tiles the last two dims of every
+block, so a full trailing dim of 1 is legal where a ``(1, bq)`` row slice of
+a 2-D array is not.
 
 Backward: the standard two-pass flash schedule behind ``jax.custom_vjp``.
 Residuals are (q, k, v, out, lse); the (Sq, Sk) probability blocks are
@@ -96,13 +99,13 @@ def _fa_kernel(
         qpos, kpos = _positions(qi, kb, bq, bk, q_offset)
         s = jnp.where(_tile_mask(qpos, kpos, causal, window), s, NEG_INF)
 
-        m_prev, l_prev = m_scr[...], l_scr[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        p = jnp.exp(s - m_new[:, None])
+        m_prev, l_prev = m_scr[...], l_scr[...]  # (bq, 1) columns
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
         corr = jnp.exp(m_prev - m_new)
-        l_scr[...] = l_prev * corr + jnp.sum(p, axis=-1)
+        l_scr[...] = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
         m_scr[...] = m_new
-        acc_scr[...] = acc_scr[...] * corr[:, None] + jnp.dot(
+        acc_scr[...] = acc_scr[...] * corr + jnp.dot(
             p.astype(v_ref.dtype), v_ref[0], preferred_element_type=jnp.float32
         )
 
@@ -115,7 +118,7 @@ def _fa_kernel(
     @pl.when(kb == nk - 1)
     def _write():
         l = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0] = (acc_scr[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
         lse_ref[0] = m_scr[...] + jnp.log(l)
 
 
@@ -135,9 +138,9 @@ def _fa_bwd_dq_kernel(
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
         qpos, kpos = _positions(qi, kb, bq, bk, q_offset)
         s = jnp.where(_tile_mask(qpos, kpos, causal, window), s, NEG_INF)
-        p = jnp.exp(s - lse_ref[0][:, None])  # (bq, bk), masked entries -> 0
+        p = jnp.exp(s - lse_ref[0])  # (bq, bk), masked entries -> 0
         dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0][:, None]) * scale
+        ds = p * (dp - delta_ref[0]) * scale
         dq_acc[...] += jnp.dot(
             ds, k.astype(jnp.float32), preferred_element_type=jnp.float32
         )
@@ -171,13 +174,13 @@ def _fa_bwd_dkv_kernel(
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
         qpos, kpos = _positions(qi, ki, bq, bk, q_offset)
         s = jnp.where(_tile_mask(qpos, kpos, causal, window), s, NEG_INF)
-        p = jnp.exp(s - lse_ref[0][:, None])  # (bq, bk)
+        p = jnp.exp(s - lse_ref[0])  # (bq, bk)
         dv_acc[...] += jax.lax.dot_general(  # p^T @ do -> (bk, d)
             p, do.astype(jnp.float32), (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
         dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0][:, None]) * scale
+        ds = p * (dp - delta_ref[0]) * scale
         dk_acc[...] += jax.lax.dot_general(  # ds^T @ q -> (bk, d)
             ds, q.astype(jnp.float32), (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -208,7 +211,7 @@ def _tiling(Sq: int, Sk: int, blocks: Tuple[int, int]):
 
 
 def _fa_call(q, k, v, causal, window, scale, blocks, interpret):
-    """Shared forward: returns (out in the public layout, lse (B*H, Sq))."""
+    """Shared forward: returns (out in the public layout, lse (B*H, Sq, 1))."""
     B, Sq, H, d = q.shape
     Sk, KV = k.shape[1], k.shape[2]
     G = H // KV
@@ -232,15 +235,15 @@ def _fa_call(q, k, v, causal, window, scale, blocks, interpret):
         ],
         out_specs=[
             pl.BlockSpec((1, bq, d), lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((1, bq), lambda bh, qi, ki: (bh, qi)),
+            pl.BlockSpec((1, bq, 1), lambda bh, qi, ki: (bh, qi, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B * H, Sq, d), q.dtype),
-            jax.ShapeDtypeStruct((B * H, Sq), jnp.float32),
+            jax.ShapeDtypeStruct((B * H, Sq, 1), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((bq,), jnp.float32),
-            pltpu.VMEM((bq,), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, d), jnp.float32),
         ],
         interpret=interpret,
@@ -280,7 +283,7 @@ def _fa_bwd(causal, window, scale, blocks, interpret, res, dout):
     delta = (
         jnp.sum(dout.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
         .transpose(0, 2, 1)
-        .reshape(BH, Sq)
+        .reshape(BH, Sq, 1)
     )
 
     dq_h = pl.pallas_call(
@@ -294,8 +297,8 @@ def _fa_bwd(causal, window, scale, blocks, interpret, res, dout):
             pl.BlockSpec((1, bk, d), lambda bh, qi, ki, G=G: (bh // G, ki, 0)),
             pl.BlockSpec((1, bk, d), lambda bh, qi, ki, G=G: (bh // G, ki, 0)),
             pl.BlockSpec((1, bq, d), lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((1, bq), lambda bh, qi, ki: (bh, qi)),
-            pl.BlockSpec((1, bq), lambda bh, qi, ki: (bh, qi)),
+            pl.BlockSpec((1, bq, 1), lambda bh, qi, ki: (bh, qi, 0)),
+            pl.BlockSpec((1, bq, 1), lambda bh, qi, ki: (bh, qi, 0)),
         ],
         out_specs=pl.BlockSpec((1, bq, d), lambda bh, qi, ki: (bh, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, Sq, d), qh.dtype),
@@ -314,8 +317,8 @@ def _fa_bwd(causal, window, scale, blocks, interpret, res, dout):
             pl.BlockSpec((1, bk, d), lambda bkv, ki, g, qi: (bkv, ki, 0)),
             pl.BlockSpec((1, bk, d), lambda bkv, ki, g, qi: (bkv, ki, 0)),
             pl.BlockSpec((1, bq, d), lambda bkv, ki, g, qi, G=G: (bkv * G + g, qi, 0)),
-            pl.BlockSpec((1, bq), lambda bkv, ki, g, qi, G=G: (bkv * G + g, qi)),
-            pl.BlockSpec((1, bq), lambda bkv, ki, g, qi, G=G: (bkv * G + g, qi)),
+            pl.BlockSpec((1, bq, 1), lambda bkv, ki, g, qi, G=G: (bkv * G + g, qi, 0)),
+            pl.BlockSpec((1, bq, 1), lambda bkv, ki, g, qi, G=G: (bkv * G + g, qi, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, bk, d), lambda bkv, ki, g, qi: (bkv, ki, 0)),

@@ -1,8 +1,10 @@
 """jit'd public wrappers for the Pallas kernels.
 
-On non-TPU backends (this container) the kernels execute in interpret mode
-— the kernel body runs in Python on CPU for correctness validation; on TPU
-they compile to Mosaic. The dispatch subsystem (``core/dispatch``) calls
+On a TPU the kernels compile to Mosaic. On the CPU backend they execute in
+interpret mode — the kernel body runs in Python for correctness validation
+(the test suite); any other backend is an error, so a kernel path can
+never silently degrade to the interpreter on an accelerator. The dispatch
+subsystem (``core/dispatch``) calls
 ``expert_gemm`` (padded layout) or ``grouped_gemm`` (sorted layout) when
 ``use_kernel=True``; ``models/attention.py`` calls ``flash_attention`` in
 place of the blockwise XLA path. All three are differentiable
@@ -25,7 +27,15 @@ from repro.kernels import paged_attention as _pa
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"Pallas kernels target the TPU (interpret mode on the CPU for "
+        f"tests); backend {backend!r} has neither"
+    )
 
 
 # ---------------------------------------------------------------------------
@@ -190,12 +200,7 @@ def grouped_gemm_q8(xs, w_gate, w_up, w_down, s_gate, s_up, s_down,
 
 def grouped_gemm_xla(xs, w_gate, w_up, w_down, group_sizes):
     """XLA path for the sorted layout (compact buffer, row_block=1):
-    ``lax.ragged_dot`` is the native grouped GEMM; falls back to the
-    per-expert masked reference when unavailable."""
-    if not hasattr(jax.lax, "ragged_dot"):
-        from repro.kernels.ref import grouped_gemm_ref
-
-        return grouped_gemm_ref(xs, w_gate, w_up, w_down, group_sizes)
+    ``lax.ragged_dot`` is the native grouped GEMM."""
     g = jax.lax.ragged_dot(xs, w_gate, group_sizes)
     u = jax.lax.ragged_dot(xs, w_up, group_sizes)
     h = jax.nn.silu(g.astype(jnp.float32)).astype(xs.dtype) * u
@@ -271,7 +276,7 @@ def _pa_cost(B: int, KV: int, G: int, maxP: int, ps: int, d: int, it: int):
 
 def _pa_page_block(kernel: str, q, k_pool, block_table, itemsize: int):
     B, H, d = q.shape
-    _, ps, KV, _ = k_pool.shape
+    _, KV, ps, _ = k_pool.shape
     maxP = block_table.shape[1]
     (bps,) = _at.get_blocks(
         kernel,
@@ -290,7 +295,7 @@ def paged_attention(
     window: Optional[int] = None, scale: Optional[float] = None,
 ):
     """Single-token decode against the block-table KV pool: q (B,H,d),
-    pools (num_pages, page_size, KV, d), block_table (B, max_pages) int32
+    pools (num_pages, KV, page_size, d), block_table (B, max_pages) int32
     (-1 = unassigned), seq_lens (B,). The page gather happens inside the
     kernel via scalar-prefetched block tables."""
     bps = _pa_page_block("paged_attention", q, k_pool, block_table,
@@ -306,7 +311,7 @@ def paged_attention_q8(
     window: Optional[int] = None, scale: Optional[float] = None,
 ):
     """int8-KV decode: pools are int8 with per-token/kv-head f32 scale
-    sidecars shaped (num_pages, page_size, KV, 1); the kernel dequantizes
+    sidecars shaped (num_pages, KV, page_size, 1); the kernel dequantizes
     each page tile in VMEM after the scalar-prefetched block-table DMA."""
     bps = _pa_page_block("paged_attention_q8", q, k_pool, block_table, 1)
     return _pa.paged_attention_q8(

@@ -1,10 +1,12 @@
 """Pallas TPU paged-attention decode kernel.
 
 Single-token decode against a block-table KV cache: the KV pool lives in HBM
-as ``(num_pages, page_size, KV, d)`` shared by every sequence, and each
-sequence owns an ordered page list ``block_table[b]`` (logical slot ``j``
-maps to ``pool[block_table[b, j // page_size], j % page_size]`` — identity
-position mapping, pages never wrap).
+as ``(num_pages, KV, page_size, d)`` shared by every sequence, and each
+sequence owns an ordered page list ``block_table[b]`` (logical slot ``j`` of
+KV head ``h`` maps to ``pool[block_table[b, j // page_size], h, j %
+page_size]`` — identity position mapping, pages never wrap). A KV head's
+``(page_size, d)`` tile is the minor pair of the pool, so one grid step's
+block is a whole TPU tile run rather than a strided row slice.
 
 The gather happens *inside* the kernel: ``block_table`` and ``seq_lens`` are
 scalar-prefetched (``PrefetchScalarGridSpec``) so the BlockSpec index map
@@ -64,21 +66,21 @@ def _pa_kernel(
 
     def _compute():
         q = q_ref[0, 0]  # (G, d)
-        k = k_ref[0, :, 0]  # (bps, d)
+        k = k_ref[0, 0]  # (bps, d)
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale  # (G, bps)
         kpos = start + jax.lax.broadcasted_iota(jnp.int32, (1, bps), 1)
         mask = kpos < n
         if window is not None:
             mask = jnp.logical_and(mask, kpos > n - 1 - window)
         s = jnp.where(mask, s, NEG_INF)
-        m_prev, l_prev = m_scr[...], l_scr[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        p = jnp.exp(s - m_new[:, None])
+        m_prev, l_prev = m_scr[...], l_scr[...]  # (G, 1) columns
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
         corr = jnp.exp(m_prev - m_new)
-        l_scr[...] = l_prev * corr + jnp.sum(p, axis=-1)
+        l_scr[...] = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
         m_scr[...] = m_new
-        acc_scr[...] = acc_scr[...] * corr[:, None] + jnp.dot(
-            p.astype(v_ref.dtype), v_ref[0, :, 0], preferred_element_type=jnp.float32
+        acc_scr[...] = acc_scr[...] * corr + jnp.dot(
+            p.astype(v_ref.dtype), v_ref[0, 0], preferred_element_type=jnp.float32
         )
 
     pl.when(live)(_compute)
@@ -88,9 +90,9 @@ def _pa_kernel(
         # fully-masked sequences (l == 0) emit zeros, matching the oracle
         l = l_scr[...]
         safe = jnp.maximum(l, 1e-30)
-        o_ref[0, 0] = jnp.where(
-            (l > 0)[:, None], acc_scr[...] / safe[:, None], 0.0
-        ).astype(o_ref.dtype)
+        o_ref[0, 0] = jnp.where(l > 0, acc_scr[...] / safe, 0.0).astype(
+            o_ref.dtype
+        )
 
 
 @functools.partial(
@@ -98,8 +100,8 @@ def _pa_kernel(
 )
 def paged_attention(
     q: jax.Array,  # (B, H, d) one query token per sequence
-    k_pool: jax.Array,  # (num_pages, page_size, KV, d)
-    v_pool: jax.Array,  # (num_pages, page_size, KV, d)
+    k_pool: jax.Array,  # (num_pages, KV, page_size, d)
+    v_pool: jax.Array,  # (num_pages, KV, page_size, d)
     block_table: jax.Array,  # (B, max_pages) int32, -1 = unassigned
     seq_lens: jax.Array,  # (B,) int32 valid tokens (incl. current)
     window: Optional[int] = None,
@@ -108,7 +110,7 @@ def paged_attention(
     page_block: Optional[int] = None,
 ) -> jax.Array:
     B, H, d = q.shape
-    num_pages, ps, KV, _ = k_pool.shape
+    num_pages, KV, ps, _ = k_pool.shape
     maxP = block_table.shape[1]
     G = H // KV
     assert H % KV == 0, (H, KV)
@@ -122,6 +124,9 @@ def paged_attention(
     qg = q.reshape(B, KV, G, d)
     bt = block_table.astype(jnp.int32)
     sl = seq_lens.astype(jnp.int32)
+    _page = lambda b, kv, j, bt, sl: (
+        jnp.maximum(bt[b, j // nsub], 0), kv, j % nsub, 0
+    )
 
     out = pl.pallas_call(
         functools.partial(
@@ -133,25 +138,15 @@ def paged_attention(
             grid=(B, KV, maxP * nsub),
             in_specs=[
                 pl.BlockSpec((1, 1, G, d), lambda b, kv, j, bt, sl: (b, kv, 0, 0)),
-                pl.BlockSpec(
-                    (1, bps, 1, d),
-                    lambda b, kv, j, bt, sl: (
-                        jnp.maximum(bt[b, j // nsub], 0), j % nsub, kv, 0
-                    ),
-                ),
-                pl.BlockSpec(
-                    (1, bps, 1, d),
-                    lambda b, kv, j, bt, sl: (
-                        jnp.maximum(bt[b, j // nsub], 0), j % nsub, kv, 0
-                    ),
-                ),
+                pl.BlockSpec((1, 1, bps, d), _page),
+                pl.BlockSpec((1, 1, bps, d), _page),
             ],
             out_specs=pl.BlockSpec(
                 (1, 1, G, d), lambda b, kv, j, bt, sl: (b, kv, 0, 0)
             ),
             scratch_shapes=[
-                pltpu.VMEM((G,), jnp.float32),
-                pltpu.VMEM((G,), jnp.float32),
+                pltpu.VMEM((G, 1), jnp.float32),
+                pltpu.VMEM((G, 1), jnp.float32),
                 pltpu.VMEM((G, d), jnp.float32),
             ],
         ),
@@ -199,21 +194,21 @@ def _pa_kernel_q8(
 
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)  # (G, d)
-        k = k_ref[0, :, 0].astype(jnp.float32) * ks_ref[0, :, 0]  # (bps, d)
+        k = k_ref[0, 0].astype(jnp.float32) * ks_ref[0, 0]  # (bps, d)
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
         kpos = start + jax.lax.broadcasted_iota(jnp.int32, (1, bps), 1)
         mask = kpos < n
         if window is not None:
             mask = jnp.logical_and(mask, kpos > n - 1 - window)
         s = jnp.where(mask, s, NEG_INF)
-        m_prev, l_prev = m_scr[...], l_scr[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        p = jnp.exp(s - m_new[:, None])
+        m_prev, l_prev = m_scr[...], l_scr[...]  # (G, 1) columns
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
         corr = jnp.exp(m_prev - m_new)
-        l_scr[...] = l_prev * corr + jnp.sum(p, axis=-1)
+        l_scr[...] = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
         m_scr[...] = m_new
-        v = v_ref[0, :, 0].astype(jnp.float32) * vs_ref[0, :, 0]  # (bps, d)
-        acc_scr[...] = acc_scr[...] * corr[:, None] + jnp.dot(
+        v = v_ref[0, 0].astype(jnp.float32) * vs_ref[0, 0]  # (bps, d)
+        acc_scr[...] = acc_scr[...] * corr + jnp.dot(
             p, v, preferred_element_type=jnp.float32
         )
 
@@ -223,9 +218,9 @@ def _pa_kernel_q8(
     def _write():
         l = l_scr[...]
         safe = jnp.maximum(l, 1e-30)
-        o_ref[0, 0] = jnp.where(
-            (l > 0)[:, None], acc_scr[...] / safe[:, None], 0.0
-        ).astype(o_ref.dtype)
+        o_ref[0, 0] = jnp.where(l > 0, acc_scr[...] / safe, 0.0).astype(
+            o_ref.dtype
+        )
 
 
 @functools.partial(
@@ -233,10 +228,10 @@ def _pa_kernel_q8(
 )
 def paged_attention_q8(
     q: jax.Array,  # (B, H, d) one query token per sequence
-    k_pool: jax.Array,  # (num_pages, page_size, KV, d) int8
-    v_pool: jax.Array,  # (num_pages, page_size, KV, d) int8
-    k_scale: jax.Array,  # (num_pages, page_size, KV, 1) f32 sidecar
-    v_scale: jax.Array,  # (num_pages, page_size, KV, 1)
+    k_pool: jax.Array,  # (num_pages, KV, page_size, d) int8
+    v_pool: jax.Array,  # (num_pages, KV, page_size, d) int8
+    k_scale: jax.Array,  # (num_pages, KV, page_size, 1) f32 sidecar
+    v_scale: jax.Array,  # (num_pages, KV, page_size, 1)
     block_table: jax.Array,  # (B, max_pages) int32, -1 = unassigned
     seq_lens: jax.Array,  # (B,) int32 valid tokens (incl. current)
     window: Optional[int] = None,
@@ -245,11 +240,11 @@ def paged_attention_q8(
     page_block: Optional[int] = None,
 ) -> jax.Array:
     B, H, d = q.shape
-    num_pages, ps, KV, _ = k_pool.shape
+    num_pages, KV, ps, _ = k_pool.shape
     maxP = block_table.shape[1]
     G = H // KV
     assert H % KV == 0, (H, KV)
-    assert k_scale.shape == (num_pages, ps, KV, 1), k_scale.shape
+    assert k_scale.shape == (num_pages, KV, ps, 1), k_scale.shape
     scale = float(scale) if scale is not None else d**-0.5
     bps = int(page_block) if page_block else ps
     assert ps % bps == 0, (ps, bps)
@@ -259,7 +254,7 @@ def paged_attention_q8(
     bt = block_table.astype(jnp.int32)
     sl = seq_lens.astype(jnp.int32)
     _page = lambda b, kv, j, bt, sl: (
-        jnp.maximum(bt[b, j // nsub], 0), j % nsub, kv, 0
+        jnp.maximum(bt[b, j // nsub], 0), kv, j % nsub, 0
     )
 
     out = pl.pallas_call(
@@ -272,17 +267,17 @@ def paged_attention_q8(
             grid=(B, KV, maxP * nsub),
             in_specs=[
                 pl.BlockSpec((1, 1, G, d), lambda b, kv, j, bt, sl: (b, kv, 0, 0)),
-                pl.BlockSpec((1, bps, 1, d), _page),
-                pl.BlockSpec((1, bps, 1, d), _page),
-                pl.BlockSpec((1, bps, 1, 1), _page),
-                pl.BlockSpec((1, bps, 1, 1), _page),
+                pl.BlockSpec((1, 1, bps, d), _page),
+                pl.BlockSpec((1, 1, bps, d), _page),
+                pl.BlockSpec((1, 1, bps, 1), _page),
+                pl.BlockSpec((1, 1, bps, 1), _page),
             ],
             out_specs=pl.BlockSpec(
                 (1, 1, G, d), lambda b, kv, j, bt, sl: (b, kv, 0, 0)
             ),
             scratch_shapes=[
-                pltpu.VMEM((G,), jnp.float32),
-                pltpu.VMEM((G,), jnp.float32),
+                pltpu.VMEM((G, 1), jnp.float32),
+                pltpu.VMEM((G, 1), jnp.float32),
                 pltpu.VMEM((G, d), jnp.float32),
             ],
         ),

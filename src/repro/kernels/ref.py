@@ -117,8 +117,8 @@ def grouped_gemm_q8_ref(
 
 def paged_attention_ref(
     q: jax.Array,  # (B, H, d) one query token per sequence
-    k_pool: jax.Array,  # (num_pages, page_size, KV, d) shared page pool
-    v_pool: jax.Array,  # (num_pages, page_size, KV, d)
+    k_pool: jax.Array,  # (num_pages, KV, page_size, d) shared page pool
+    v_pool: jax.Array,  # (num_pages, KV, page_size, d)
     block_table: jax.Array,  # (B, max_pages) int32 page ids, -1 = unassigned
     seq_lens: jax.Array,  # (B,) int32 tokens valid per sequence (incl. current)
     window: Optional[int] = None,
@@ -126,17 +126,18 @@ def paged_attention_ref(
 ) -> jax.Array:
     """XLA gather oracle for the Pallas paged-attention decode kernel.
 
-    Logical KV slot ``j`` of sequence ``b`` lives at
-    ``pool[block_table[b, j // page_size], j % page_size]`` (identity
+    Logical KV slot ``j`` of sequence ``b`` and KV head ``h`` lives at
+    ``pool[block_table[b, j // page_size], h, j % page_size]`` (identity
     position mapping — pages never wrap). Slots with ``j >= seq_lens[b]``
     or an unassigned page are masked. Returns (B, H, d)."""
     B, H, d = q.shape
-    _, ps, KV, _ = k_pool.shape
+    _, KV, ps, _ = k_pool.shape
     G = H // KV
     scale = scale if scale is not None else d**-0.5
     bt = jnp.maximum(block_table, 0)
-    kg = k_pool[bt].reshape(B, -1, KV, d)  # (B, maxP*ps, KV, d)
-    vg = v_pool[bt].reshape(B, -1, KV, d)
+    # (B, maxP, KV, ps, d) -> token-major (B, maxP*ps, KV, d)
+    kg = k_pool[bt].transpose(0, 1, 3, 2, 4).reshape(B, -1, KV, d)
+    vg = v_pool[bt].transpose(0, 1, 3, 2, 4).reshape(B, -1, KV, d)
     S = kg.shape[1]
     kpos = jnp.arange(S, dtype=jnp.int32)[None, :]
     valid = (kpos < seq_lens[:, None]) & (block_table >= 0)[
@@ -159,10 +160,10 @@ def paged_attention_ref(
 
 def paged_attention_q8_ref(
     q: jax.Array,  # (B, H, d)
-    k_pool: jax.Array,  # (num_pages, page_size, KV, d) int8
-    v_pool: jax.Array,  # (num_pages, page_size, KV, d) int8
-    k_scale: jax.Array,  # (num_pages, page_size, KV, 1) per-token scales
-    v_scale: jax.Array,  # (num_pages, page_size, KV, 1)
+    k_pool: jax.Array,  # (num_pages, KV, page_size, d) int8
+    v_pool: jax.Array,  # (num_pages, KV, page_size, d) int8
+    k_scale: jax.Array,  # (num_pages, KV, page_size, 1) per-token scales
+    v_scale: jax.Array,  # (num_pages, KV, page_size, 1)
     block_table: jax.Array,  # (B, max_pages) int32 page ids, -1 = unassigned
     seq_lens: jax.Array,  # (B,) int32
     window=None,

@@ -11,6 +11,11 @@ EP (the paper's TP2CP2 <-> TP1EP8 example).
 
 ``make_serving_mesh`` — EP x DP ('data', 'expert') mesh for the sharded
 serving engine (``ServingEngine(mesh=...)``).
+
+Every mesh is built with Auto axis types: the model code places tensors
+with ``with_sharding_constraint`` / ``NamedSharding`` and lets the compiler
+propagate (``sharding/rules.py``), which JAX accepts only on Auto axes
+(``jax.make_mesh`` now defaults to Explicit ones).
 """
 from __future__ import annotations
 
@@ -18,7 +23,12 @@ from typing import Optional, Tuple
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
+
+
+def _auto_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...], devices) -> Mesh:
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -30,20 +40,20 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
         f"need {n} devices (run under XLA_FLAGS=--xla_force_host_platform_device_count=512); "
         f"have {len(devices)}"
     )
-    return jax.make_mesh(shape, axes, devices=devices[:n])
+    return _auto_mesh(shape, axes, devices[:n])
 
 
 def make_study_mesh(data: int, expert: int, model: int) -> Mesh:
     n = data * expert * model
     devices = jax.devices()
     assert len(devices) >= n, (n, len(devices))
-    return jax.make_mesh((data, expert, model), ("data", "expert", "model"), devices=devices[:n])
+    return _auto_mesh((data, expert, model), ("data", "expert", "model"), devices[:n])
 
 
 def make_host_mesh() -> Mesh:
     """1x1 mesh on the real local device — used by tests/examples so the
     sharding code paths run identically at laptop scale."""
-    return jax.make_mesh((1, 1), ("data", "model"), devices=jax.devices()[:1])
+    return _auto_mesh((1, 1), ("data", "model"), jax.devices()[:1])
 
 
 def make_serving_mesh(dp: int = 1, ep: int = 1) -> Mesh:
@@ -58,4 +68,4 @@ def make_serving_mesh(dp: int = 1, ep: int = 1) -> Mesh:
         f"serving mesh dp={dp} x ep={ep} needs {n} devices; have "
         f"{len(devices)} (set --xla_force_host_platform_device_count)"
     )
-    return jax.make_mesh((dp, ep), ("data", "expert"), devices=devices[:n])
+    return _auto_mesh((dp, ep), ("data", "expert"), devices[:n])

@@ -3,6 +3,9 @@
   PYTHONPATH=src python -m repro.launch.serve --arch qwen3-moe-30b-a3b --smoke \\
       --requests 8 --max-new 24 --cache-mode paged --page-size 16 \\
       --prefill-chunk 32
+  # published widths, depth cut to 2 layers (one TPU v5e):
+  PYTHONPATH=src python -m repro.launch.serve --arch llama3-e8t2 --layers 2 \\
+      --cache-mode paged --dispatcher sorted --use-kernel
 """
 from __future__ import annotations
 
@@ -14,6 +17,7 @@ import jax
 import numpy as np
 
 from repro.config import get_config, smoke_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import model_decl
 from repro.serving.engine import Request, ServingEngine
 from repro.sharding.rules import init_from_decls
@@ -23,6 +27,9 @@ def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="override num_layers (every width stays as "
+                         "published): the one-chip depth cut")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--max-batch", type=int, default=4)
     ap.add_argument("--max-new", type=int, default=16)
@@ -107,9 +114,18 @@ def main(argv=None):
             ap.error("--fused-dispatch requires --dispatcher sorted")
         args.dispatcher = "sorted"
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = smoke_config(cfg)
+    if args.layers:
+        cfg = cfg.replace(num_layers=args.layers)
+    if (args.layers and cfg.moe is not None and args.dp * args.ep == 1
+            and (args.dispatcher or cfg.moe.dispatcher)
+            in ("alltoall", "a2a_overlap")):
+        ap.error(f"{args.arch} defaults to the {cfg.moe.dispatcher!r} EP "
+                 "dispatcher, which needs an EP mesh (--ep); on one device "
+                 "name --dispatcher sorted")
     if cfg.family == "vlm":
         # serving demo drives the text path; image prefix handled at prefill
         cfg = cfg.replace(num_prefix_embeds=0, family="dense")

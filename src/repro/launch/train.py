@@ -10,9 +10,16 @@
       --resume
 
 ``--smoke`` selects the reduced config (CPU-runnable); without it the full
-assigned config is used (cluster scale). ``--upcycle N`` converts the dense
-config to an N-expert MoE, optionally initializing from ``--from-ckpt`` via
-online upcycling.
+assigned config is used (cluster scale). ``--layers N`` keeps every
+published width and cuts only the depth — how one TPU v5e trains an
+upcycled model at real width:
+
+  PYTHONPATH=src python -m repro.launch.train --arch stablelm-1.6b --layers 1 \\
+      --upcycle 8 --top-k 2 --cf -1 --dispatcher sorted --use-kernel \\
+      --from-ckpt experiments/dense_ckpt --steps 3 --batch 1 --seq 2048
+
+``--upcycle N`` converts the dense config to an N-expert MoE, optionally
+initializing from ``--from-ckpt`` via online upcycling.
 
 Resume semantics: ``--ckpt-every`` writes FULL TrainState checkpoints
 (params + AdamW state + RNG + data-stream snapshot) into step-numbered
@@ -31,6 +38,7 @@ import jax
 
 from repro.config import MoEConfig, TrainConfig, get_config, smoke_config
 from repro.data.pipeline import make_train_iter
+from repro.launch.compile_cache import enable_compile_cache
 from repro.train.trainer import Trainer
 
 
@@ -38,6 +46,9 @@ def build_argparser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="override num_layers (every width stays as "
+                         "published): the one-chip depth cut")
     ap.add_argument("--steps", type=int, default=100)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=128)
@@ -103,9 +114,19 @@ def main(argv=None):
         if args.dispatcher not in (None, "sorted"):
             raise SystemExit("--fused-dispatch requires --dispatcher sorted")
         args.dispatcher = "sorted"
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = smoke_config(cfg)
+    if args.layers:
+        cfg = cfg.replace(num_layers=args.layers)
+    if (args.layers and not args.upcycle and cfg.moe is not None
+            and (args.dispatcher or cfg.moe.dispatcher)
+            in ("alltoall", "a2a_overlap")):
+        raise SystemExit(f"{args.arch} defaults to the {cfg.moe.dispatcher!r} "
+                         "EP dispatcher, which needs an EP mesh; the "
+                         "launcher trains on one device: name --dispatcher "
+                         "sorted")
     ckpt_dir = args.save_ckpt or "/tmp/repro_ckpt"
 
     provenance = {}
@@ -159,6 +180,7 @@ def main(argv=None):
         dense_params = load_checkpoint(args.from_ckpt)
         params = upcycle_params(dense_cfg, cfg, dense_params,
                                 jax.random.PRNGKey(args.seed))
+        del dense_params  # device memory: the MoE state is what trains
         provenance = upcycle_provenance(dense_cfg, cfg, args.from_ckpt)
         print(f"upcycled {dense_cfg.name} -> {cfg.name} from {args.from_ckpt}")
 
@@ -177,6 +199,7 @@ def main(argv=None):
     tr = Trainer(cfg, tcfg, params=params, state=state, data_iter=it,
                  use_kernel=args.use_kernel, dispatcher=args.dispatcher,
                  step_timeout_s=args.step_timeout or None)
+    del params  # the TrainState holds its own copy
 
     from repro.train.callbacks import (
         AnomalySupervisor,

@@ -19,10 +19,12 @@ Megatron context parallelism. Decode shards the KV-cache sequence axis.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.config import ModelConfig
 from repro.models.layers import norm_apply, norm_decl, rope_apply
@@ -244,6 +246,42 @@ def _constrain_qkv(plan: Optional[FoldingPlan], t: jax.Array, kind: str, decode:
     return plan.constrain(t, "fold_batch", "attn_seq", None, None)  # cp mode
 
 
+def _gather_pages(pool: jax.Array, page_table: jax.Array) -> jax.Array:
+    """Token-major view of the pages a block table names: pool (pages, KV,
+    ps, x) + table (B, maxP) -> (B, maxP*ps, KV, x). Unassigned (-1)
+    entries read page 0; callers mask them through ``k_pos``."""
+    B, maxP = page_table.shape
+    _, KV, ps, x = pool.shape
+    g = pool[jnp.maximum(page_table, 0)]  # (B, maxP, KV, ps, x)
+    return g.transpose(0, 1, 3, 2, 4).reshape(B, maxP * ps, KV, x)
+
+
+def _paged_kernel(plan: Optional[FoldingPlan], kernel, q, pools, page_table,
+                  seq_lens, window):
+    """Run a paged-attention Pallas kernel. On a mesh it runs per DP shard
+    inside ``shard_map`` (the TPU compiler cannot partition a Mosaic
+    kernel): each shard sees its own rows and its own stride of the page
+    axis, so the block table's global page ids are shifted to local ones
+    (-1 stays unassigned). Other mesh axes see replicated operands."""
+    if plan is None:
+        return kernel(q, *pools, page_table, seq_lens, window=window)
+    axes = tuple(plan.batch_axes)
+    part = axes or None
+    local_pages = pools[0].shape[0] // math.prod(plan.mesh.shape[a] for a in axes)
+
+    def body(q, *rest):
+        *pools_l, bt, sl = rest
+        off = jax.lax.axis_index(axes) * local_pages if axes else 0
+        bt = jnp.where(bt >= 0, bt - off, -1)
+        return kernel(q, *pools_l, bt, sl, window=window)
+
+    return jax.shard_map(
+        body, mesh=plan.mesh,
+        in_specs=(P(part),) * (len(pools) + 3), out_specs=P(part),
+        check_vma=False,
+    )(q, *pools, page_table, seq_lens)
+
+
 def gqa_apply(
     cfg: ModelConfig,
     plan: Optional[FoldingPlan],
@@ -295,7 +333,7 @@ def gqa_apply(
             cache = {"k": k, "v": v}
     elif cache_view is not None and "page_table" in cache_view:
         # ---- paged decode / chunked prefill against the shared page pool --
-        # cache is the pool slice for this layer: (num_pages, ps, KV, hd).
+        # cache is the pool slice for this layer: (num_pages, KV, ps, hd).
         # cache_view: page_table (B, maxP); write_page/write_offset (B, S)
         # physical scatter targets (invalid positions -> the trash page);
         # k_pos (B, maxP*ps) logical slot validity; seq_lens (B,).
@@ -319,47 +357,44 @@ def gqa_apply(
 
             kq, ks = quantize_kv(k)
             vq, vs = quantize_kv(v)
-            k_cache = cache["k"].at[wp, wo].set(kq)
-            v_cache = cache["v"].at[wp, wo].set(vq)
-            ks_cache = cache["k_scale"].at[wp, wo].set(ks)
-            vs_cache = cache["v_scale"].at[wp, wo].set(vs)
+            k_cache = cache["k"].at[wp, :, wo].set(kq)
+            v_cache = cache["v"].at[wp, :, wo].set(vq)
+            ks_cache = cache["k_scale"].at[wp, :, wo].set(ks)
+            vs_cache = cache["v_scale"].at[wp, :, wo].set(vs)
             if use_kernel and S == 1:
                 from repro.kernels.ops import paged_attention_q8
 
-                out = paged_attention_q8(
-                    q[:, 0], k_cache, v_cache, ks_cache, vs_cache,
+                out = _paged_kernel(
+                    plan, paged_attention_q8, q[:, 0],
+                    (k_cache, v_cache, ks_cache, vs_cache),
                     cache_view["page_table"], cache_view["seq_lens"],
-                    window=cfg.sliding_window,
+                    cfg.sliding_window,
                 )[:, None]
             else:
-                KVh, hd = k_cache.shape[2], k_cache.shape[3]
-                bt = jnp.maximum(cache_view["page_table"], 0)
-                kg = dequantize_kv(
-                    k_cache[bt], ks_cache[bt], q.dtype
-                ).reshape(B, -1, KVh, hd)
-                vg = dequantize_kv(
-                    v_cache[bt], vs_cache[bt], q.dtype
-                ).reshape(B, -1, KVh, hd)
+                bt = cache_view["page_table"]
+                kg = dequantize_kv(_gather_pages(k_cache, bt),
+                                   _gather_pages(ks_cache, bt), q.dtype)
+                vg = dequantize_kv(_gather_pages(v_cache, bt),
+                                   _gather_pages(vs_cache, bt), q.dtype)
                 out = attention_core(
                     q, kg, vg, positions, cache_view["k_pos"], cfg.sliding_window
                 )
             cache = {"k": k_cache, "v": v_cache,
                      "k_scale": ks_cache, "v_scale": vs_cache}
             return jnp.einsum("bshk,hkd->bsd", out, params["wo"]), cache
-        k_cache = cache["k"].at[wp, wo].set(k)
-        v_cache = cache["v"].at[wp, wo].set(v)
+        k_cache = cache["k"].at[wp, :, wo].set(k)
+        v_cache = cache["v"].at[wp, :, wo].set(v)
         if use_kernel and S == 1:
             from repro.kernels.ops import paged_attention
 
-            out = paged_attention(
-                q[:, 0], k_cache, v_cache, cache_view["page_table"],
-                cache_view["seq_lens"], window=cfg.sliding_window,
+            out = _paged_kernel(
+                plan, paged_attention, q[:, 0], (k_cache, v_cache),
+                cache_view["page_table"], cache_view["seq_lens"],
+                cfg.sliding_window,
             )[:, None]
         else:
-            KVh, hd = k_cache.shape[2], k_cache.shape[3]
-            bt = jnp.maximum(cache_view["page_table"], 0)
-            kg = k_cache[bt].reshape(B, -1, KVh, hd)
-            vg = v_cache[bt].reshape(B, -1, KVh, hd)
+            kg = _gather_pages(k_cache, cache_view["page_table"])
+            vg = _gather_pages(v_cache, cache_view["page_table"])
             out = attention_core(
                 q, kg, vg, positions, cache_view["k_pos"], cfg.sliding_window
             )

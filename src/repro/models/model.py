@@ -206,8 +206,10 @@ def decode_step(
 
 
 def paged_stack_decl(cfg: ModelConfig, num_pages: int, page_size: int) -> Dict[str, Any]:
-    """KV page-pool declarations: per layer-slot ``(P, num_pages, page_size,
-    KV, hd)`` k/v pools shared by every sequence. By convention the LAST
+    """KV page-pool declarations: per layer-slot ``(P, num_pages, KV,
+    page_size, hd)`` k/v pools shared by every sequence (a KV head's
+    ``(page_size, hd)`` tile is the minor pair — the paged-attention
+    kernel's block). By convention the LAST
     page (index ``num_pages - 1``) is the trash page — padded positions
     scatter there and it never appears in a block table; callers allocating
     N usable pages must decl N + 1. Under EP x DP serving the pool is a
@@ -222,7 +224,7 @@ def paged_stack_decl(cfg: ModelConfig, num_pages: int, page_size: int) -> Dict[s
     ``cfg.quant_kv == "int8"`` switches the k/v payload to int8 and adds
     per-token, per-kv-head f32 scale sidecar leaves (``k_scale``/
     ``v_scale``, head dim collapsed to 1) to the same pool subtree. The
-    sidecars keep the page axis at 1 and the page_size axis at 2, so every
+    sidecars keep the page axis at 1 and the page_size axis at 3, so every
     pool-tree operation (``copy_pages`` COW, ``permute_pool`` defrag,
     ``pool_sharding`` DP split, the first-leaf shape introspection below)
     moves scales with their pages structurally."""
@@ -237,13 +239,13 @@ def paged_stack_decl(cfg: ModelConfig, num_pages: int, page_size: int) -> Dict[s
 
     def pool():
         kv_decl = lambda: ParamDecl(
-            (periods, num_pages, page_size, kv, hd),
+            (periods, num_pages, kv, page_size, hd),
             ("layers", None, None, None, None), "zeros", dt,
         )
         attn = {"k": kv_decl(), "v": kv_decl()}
         if quant:
             scale_decl = lambda: ParamDecl(
-                (periods, num_pages, page_size, kv, 1),
+                (periods, num_pages, kv, page_size, 1),
                 ("layers", None, None, None, None), "zeros", jnp.float32,
             )
             attn["k_scale"] = scale_decl()
@@ -286,8 +288,8 @@ def paged_forward(
     token after draft token j, so one chunk scores k drafts at once
     (positions >= valid_len are pad garbage; callers mask by length)."""
     B, S = tokens.shape
-    leaf = jax.tree.leaves(pool["stack"])[0]  # (P, num_pages, ps, KV, hd)
-    num_pages, ps = leaf.shape[1], leaf.shape[2]
+    leaf = jax.tree.leaves(pool["stack"])[0]  # (P, num_pages, KV, ps, hd)
+    num_pages, ps = leaf.shape[1], leaf.shape[3]
     maxP = page_table.shape[1]
     trash = (
         jnp.full((B, 1), num_pages - 1, jnp.int32)

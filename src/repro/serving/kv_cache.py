@@ -1,8 +1,8 @@
 """Block-table KV cache: a fixed-size page pool shared by every sequence,
 optionally partitioned into per-DP-shard sub-pools.
 
-The device side is a per-layer ``(num_shards * (pages_per_shard + 1),
-page_size, KV, hd)`` k/v pool (``models.model.paged_stack_decl``): each DP
+The device side is a per-layer ``(num_shards * (pages_per_shard + 1), KV,
+page_size, hd)`` k/v pool (``models.model.paged_stack_decl``): each DP
 shard owns a contiguous stride of ``pages_per_shard`` usable pages plus its
 own trash page (the slot padded positions scatter into), so the page axis
 shards evenly over the mesh 'data' axis and every row's page gather stays
@@ -14,8 +14,8 @@ tracking which physical pages each request owns (a request's pages all
 come from ONE shard: its KV must be co-resident with its batch row) — plus
 per-slot block tables mapping logical page index -> physical page.
 
-Logical KV slot ``j`` of a request maps to
-``pool[table[j // page_size], j % page_size]``: the identity position
+Logical KV slot ``j`` of a request and KV head ``h`` maps to
+``pool[table[j // page_size], h, j % page_size]``: the identity position
 mapping. Unlike the ring buffer, pages never wrap; a sliding-window config
 instead *releases* pages that fall entirely below the window (the window
 mask already excludes them, so the tokens are dead).
@@ -605,7 +605,7 @@ def init_paged_pool(
 
 
 def pool_sharding(plan: FoldingPlan):
-    """NamedSharding for pool leaves ``(P, pages, ps, KV, hd)``: the page
+    """NamedSharding for pool leaves ``(P, pages, KV, ps, hd)``: the page
     axis shards over the mesh batch axes (one stride per DP shard)."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -616,7 +616,7 @@ def pool_sharding(plan: FoldingPlan):
 
 def permute_pool(pool, mapping: Dict[int, int]):
     """Apply a defrag mapping to the device pool: page ``old`` moves to
-    index ``new``. Leaves are (P, num_pages, ps, KV, hd); the trash page is
+    index ``new``. Leaves are (P, num_pages, KV, ps, hd); the trash page is
     never remapped."""
     n = jax.tree.leaves(pool)[0].shape[1]
     src = np.arange(n)

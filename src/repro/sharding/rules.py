@@ -26,7 +26,7 @@ from typing import Any, Callable, Dict, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 # Ordered candidate mesh-axis tuples per logical axis. ``None`` = replicated.
 # Resolution picks the first candidate whose axes (a) all exist in the mesh,
@@ -129,6 +129,12 @@ class FoldingPlan:
 
     @staticmethod
     def make(cfg: Any, mesh: Mesh) -> "FoldingPlan":
+        # the plan places tensors through sharding constraints and lets the
+        # compiler propagate, which JAX allows only on Auto axes; a mesh
+        # from jax.make_mesh (Explicit axes by default) is re-typed
+        if any(t != AxisType.Auto for t in mesh.axis_types):
+            mesh = Mesh(mesh.devices, mesh.axis_names,
+                        axis_types=(AxisType.Auto,) * len(mesh.axis_names))
         model_size = mesh.shape.get("model", 1)
         heads = getattr(cfg, "num_heads", 0)
         attn_mode = "tp" if heads and heads % model_size == 0 else "cp"

@@ -110,8 +110,6 @@ def test_roofline_analyzer_known_program():
     compiled = jax.jit(scanned).lower(x, ws).compile()
     got = analyze(compiled.as_text()).flops
     assert got == 6 * 2 * 64**3, got
-    ca = compiled.cost_analysis()  # list of per-device dicts on older jax
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0]
+    ca = compiled.cost_analysis()
     builtin = float(ca.get("flops", 0))
     assert builtin < got  # documents the builtin undercount

@@ -13,8 +13,8 @@ from repro.kernels.ref import paged_attention_ref
 def _scatter_case(rng, B, H, KV, d, ps, maxP, num_pages, lens):
     """Random pool + disjoint per-sequence page lists covering `lens`."""
     q = jnp.asarray(rng.standard_normal((B, H, d)), jnp.float32)
-    k_pool = jnp.asarray(rng.standard_normal((num_pages, ps, KV, d)), jnp.float32)
-    v_pool = jnp.asarray(rng.standard_normal((num_pages, ps, KV, d)), jnp.float32)
+    k_pool = jnp.asarray(rng.standard_normal((num_pages, KV, ps, d)), jnp.float32)
+    v_pool = jnp.asarray(rng.standard_normal((num_pages, KV, ps, d)), jnp.float32)
     perm = rng.permutation(num_pages)
     bt = np.full((B, maxP), -1, np.int32)
     used = 0
@@ -79,8 +79,8 @@ def test_ref_matches_dense_attention():
     v = rng.standard_normal((B, S, KV, d)).astype(np.float32)
     q = jnp.asarray(rng.standard_normal((B, H, d)), jnp.float32)
     # pool page b*maxP + j holds sequence b's tokens [j*ps, (j+1)*ps)
-    k_pool = jnp.asarray(k.reshape(B * maxP, ps, KV, d))
-    v_pool = jnp.asarray(v.reshape(B * maxP, ps, KV, d))
+    k_pool = jnp.asarray(k.reshape(B * maxP, ps, KV, d).transpose(0, 2, 1, 3))
+    v_pool = jnp.asarray(v.reshape(B * maxP, ps, KV, d).transpose(0, 2, 1, 3))
     bt = jnp.asarray(np.arange(B * maxP).reshape(B, maxP).astype(np.int32))
     out = paged_attention_ref(q, k_pool, v_pool, bt, jnp.asarray(lens, jnp.int32))
 
@@ -103,8 +103,8 @@ def test_kernel_idle_sequence_emits_zeros():
     rng = np.random.default_rng(1)
     B, H, KV, d, ps, maxP = 2, 4, 2, 32, 4, 4
     q = jnp.asarray(rng.standard_normal((B, H, d)), jnp.float32)
-    kp = jnp.asarray(rng.standard_normal((maxP, ps, KV, d)), jnp.float32)
-    vp = jnp.asarray(rng.standard_normal((maxP, ps, KV, d)), jnp.float32)
+    kp = jnp.asarray(rng.standard_normal((maxP, KV, ps, d)), jnp.float32)
+    vp = jnp.asarray(rng.standard_normal((maxP, KV, ps, d)), jnp.float32)
     bt = np.full((B, maxP), -1, np.int32)
     bt[0, :2] = [0, 1]
     out = paged_attention(q, kp, vp, jnp.asarray(bt), jnp.asarray([5, 0], jnp.int32))

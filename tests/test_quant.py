@@ -134,9 +134,9 @@ def test_paged_attention_q8_matches_oracle(rng):
 
     P, ps, B, H, KV, d, maxP = 16, 8, 3, 4, 2, 64, 4
     kq, ks = quantize_kv(
-        jnp.asarray(rng.standard_normal((P, ps, KV, d)), jnp.bfloat16) * 0.3)
+        jnp.asarray(rng.standard_normal((P, KV, ps, d)), jnp.bfloat16) * 0.3)
     vq, vs = quantize_kv(
-        jnp.asarray(rng.standard_normal((P, ps, KV, d)), jnp.bfloat16) * 0.3)
+        jnp.asarray(rng.standard_normal((P, KV, ps, d)), jnp.bfloat16) * 0.3)
     q = jnp.asarray(rng.standard_normal((B, H, d)), jnp.bfloat16) * 0.3
     bt = jnp.asarray(rng.permutation(P)[: B * maxP].reshape(B, maxP), jnp.int32)
     sl = jnp.asarray(rng.integers(1, maxP * ps, B), jnp.int32)

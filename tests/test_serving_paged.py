@@ -63,6 +63,63 @@ def _parity(cfg, params, paged_kw, seed=11, n=6, max_batch=3, max_seq=64,
     return paged
 
 
+def test_e8t2_one_device_default_dispatcher_fails_loudly(monkeypatch):
+    """llama3-e8t2 defaults to the EP 'alltoall' dispatcher. A one-device
+    engine has no EP mesh, so under strict dispatch its first step must
+    raise instead of quietly running allgather; naming 'sorted' is the
+    one-chip path. (smoke_config swaps alltoall for allgather, so the
+    published default is put back.)"""
+    monkeypatch.setenv("REPRO_STRICT_DISPATCH", "1")
+    default = get_config("llama3-e8t2").moe.dispatcher
+    assert default == "alltoall"
+    cfg = smoke_config(get_config("llama3-e8t2"))
+    cfg = cfg.replace(moe=dataclasses.replace(cfg.moe, dispatcher=default))
+    params = init_model(cfg)
+    req = lambda: Request(rid=0, prompt=np.arange(5, dtype=np.int32),  # noqa: E731
+                          max_new_tokens=2)
+    eng = ServingEngine(cfg, params, max_batch=2, max_seq=32, cache_mode="paged")
+    eng.submit(req())
+    with pytest.raises(ValueError, match="'alltoall' is illegal"):
+        eng.step()
+    eng = ServingEngine(cfg, params, max_batch=2, max_seq=32, cache_mode="paged",
+                        dispatcher="sorted")
+    assert len(eng.run([req()])[0]) == 2
+
+
+def test_serve_launcher_layers_cuts_depth_only(monkeypatch):
+    """--layers overrides num_layers and leaves every width alone."""
+    from repro.launch import serve
+
+    seen = []
+
+    class Spy(ServingEngine):
+        def __init__(self, cfg, *a, **kw):
+            seen.append(cfg)
+            super().__init__(cfg, *a, **kw)
+
+    from repro.launch import train
+
+    monkeypatch.setattr(serve, "ServingEngine", Spy)
+    # the launchers' persistent compile cache would stay on for every later
+    # test in this worker; tests/test_compile_cache.py covers it
+    for launcher in (serve, train):
+        monkeypatch.setattr(launcher, "enable_compile_cache", lambda: None)
+    out = serve.main(["--arch", "llama3-e8t2", "--smoke", "--layers", "1",
+                      "--dispatcher", "sorted", "--cache-mode", "paged",
+                      "--requests", "1", "--max-new", "2", "--prompt-len", "4"])
+    assert [len(v) for v in out.values()] == [2]
+    smoke = smoke_config(get_config("llama3-e8t2"))
+    assert seen[0].num_layers == 1
+    assert seen[0].replace(num_layers=smoke.num_layers).param_counts() == \
+        smoke.param_counts()
+    # at published widths without an EP mesh, the EP default is refused
+    # before any weight is allocated
+    with pytest.raises(SystemExit):
+        serve.main(["--arch", "llama3-e8t2", "--layers", "2"])
+    with pytest.raises(SystemExit, match="--dispatcher sorted"):
+        train.main(["--arch", "llama3-e8t2", "--layers", "1"])
+
+
 SWEEP = {
     "llama3-e8t2": {},
     "llama3-8b": {},
