@@ -5,15 +5,20 @@ The mix's ``kind`` names the module ``kinds/<kind>.py``; its ``run`` does
 set-up, the measured window and the check against the reference, and
 returns a :class:`Run`: the end-to-end numbers, the compared numbers with
 their limits, and for a traced run the context that the per-layer metric
-readers read. What the kinds share is here.
+readers read. A kind may also define ``read_trace(ctx, device)``, which
+reduces the loaded trace once to what its readers share and returns the
+context they read. What the kinds share is here.
 """
 from __future__ import annotations
 
 import contextlib
 import dataclasses
 import math
+import os
 import shutil
+import sys
 import tempfile
+import time
 import types
 from typing import Any, Dict, List, Tuple
 
@@ -155,10 +160,14 @@ def execute(cell: bench.Cell, seed: int, seconds: float, trace_on: bool,
     metrics: Dict[str, Dict[str, Any]] = {}
     out: Dict[str, Any] = {}
     if trace_on:
+        t0 = time.perf_counter()
+        size = os.path.getsize(run.ctx["trace_path"])
         ctx = reduce_trace(run.ctx, len(devs), devs[0].device_kind)
         lo, hi = ctx["lo"], ctx["hi"]
         device["busy_s"] = trace.mean_busy_ns(ctx["trace"], lo, hi) * 1e-9
         device["window_s"] = (hi - lo) * 1e-9
+        if hasattr(kind, "read_trace"):
+            ctx = kind.read_trace(ctx, device)
         for m in cell.per_layer:
             value = bench.metric_reader(m["name"], cell.here).read(ctx)
             if value is not None:
@@ -167,6 +176,7 @@ def execute(cell: bench.Cell, seed: int, seconds: float, trace_on: bool,
             "device_ops": [[n, s] for n, s in trace.top_ops(ctx["trace"], lo, hi)],
             "idle_gaps": [[n, s] for n, s in trace.attribute_gaps(ctx["trace"], lo, hi)],
         }
+        print(f"trace: {size} bytes reduced in {time.perf_counter() - t0!r} s", file=sys.stderr)
     else:
         for m in cell.end_to_end:
             metrics[m["name"]] = {"value": run.e2e[m["name"]], "unit": units[m["name"]]}

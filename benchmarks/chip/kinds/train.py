@@ -25,7 +25,7 @@ from typing import Dict, Iterator, List
 
 import numpy as np
 
-from benchmarks.chip import bench, gen, harness, reference
+from benchmarks.chip import bench, gen, harness, reference, trace
 
 
 def batches(t: Dict, vocab: int, seed: int) -> Iterator[Dict[str, np.ndarray]]:
@@ -185,6 +185,35 @@ def run(cell: bench.Cell, seed: int, seconds: float, trace_on: bool,
                 "update_gap": (upd_gap, lim["update_gap"])},
         attempted=steps, failed=0,
         ctx={"kind": "train", "steps": steps, "tokens": tokens, "dims": d,
-             "traffic": t, "trace_path": tr_file.path, "trace_dir": tr_file.dir,
-             "memory_peak_bytes": mem},
+             "traffic": t, "remat": moe_cfg.remat, "trace_path": tr_file.path,
+             "trace_dir": tr_file.dir, "memory_peak_bytes": mem},
     )
+
+
+def read_trace(ctx: Dict, device: Dict) -> Dict:
+    """The traced window's device time by train-step phase (``trace.phase``),
+    read once for the phase readers: ``phase_ms``, milliseconds per step
+    for each phase, ``other`` and ``idle``, which make up the window over
+    the steps. Printed to standard error with each phase's five largest op
+    families. Raises where the step is out of the reader's sight (its jit
+    name or its op-name paths have changed): a forward, backward or update
+    phase with no op, or no recompute while the configuration remats."""
+    fams = trace.phase_family_ns(ctx["trace"], ctx["lo"], ctx["hi"])
+    per = 1e-6 / ctx["steps"]
+    ms = {p: sum(f.values()) * per for p, f in fams.items()}
+    need = [p for p in trace.PHASES if p != "remat_recompute" or ctx["remat"] != "none"]
+    empty = [p for p in need if not ms[p]]
+    if empty:
+        raise RuntimeError(
+            f"trace: no device op of the window under {trace.STEP_MODULE!r} is in "
+            f"phase {', '.join(empty)} (remat {ctx['remat']!r}); trace.phase no "
+            "longer sees the train step")
+    ms["idle"] = (device["window_s"] - device["busy_s"]) * 1e3 / ctx["steps"]
+    print("trace: device ms per step: " + ", ".join(f"{k} {v!r}" for k, v in ms.items())
+          + f"; window / steps {device['window_s'] * 1e3 / ctx['steps']!r} over "
+          f"{ctx['steps']} steps", file=sys.stderr)
+    for p, f in fams.items():
+        top = sorted(f.items(), key=lambda kv: -kv[1])[:5]
+        print(f"trace: {p} top ms per step: "
+              + ", ".join(f"{name} {ns * per!r}" for name, ns in top), file=sys.stderr)
+    return {**ctx, "phase_ms": ms}
