@@ -119,11 +119,13 @@ class AllToAllDispatcher(TokenDispatcher):
                         E, C, ep, E_loc, ep_axis, use_kernel):
         """Per-shard dispatch -> expert FFN -> combine (inside shard_map).
         Subclasses override this to change the exchange schedule."""
-        xe, state = self.dispatch(
-            x_l, idx_l, gates_l, E=E, C=C, ep=ep, E_loc=E_loc, ep_axis=ep_axis
-        )
+        with jax.named_scope("moe_dispatch"):
+            xe, state = self.dispatch(
+                x_l, idx_l, gates_l, E=E, C=C, ep=ep, E_loc=E_loc, ep_axis=ep_axis
+            )
         ye = expert_ffn(experts_l, xe[None], state.layout, use_kernel)[0]
-        return self.combine(ye, state)
+        with jax.named_scope("moe_combine"):
+            return self.combine(ye, state)
 
 
 class OverlapAllToAllDispatcher(AllToAllDispatcher):
@@ -144,31 +146,35 @@ class OverlapAllToAllDispatcher(AllToAllDispatcher):
     def _local_pipeline(self, x_l, gates_l, idx_l, experts_l, *,
                         E, C, ep, E_loc, ep_axis, use_kernel):
         T_loc, D = x_l.shape
-        sel, slot_gate = dispatch_tables(idx_l, gates_l, E, C)  # (E, C)
-        send = x_l[sel].reshape(ep, E_loc, C, D)  # [j] = slots for shard j
-        my = jax.lax.axis_index(ep_axis)
-        # rolled[r] = block destined to shard (my + r) % ep; round 0 local
-        rolled = jnp.roll(send, -my, axis=0)
+        with jax.named_scope("moe_dispatch"):
+            sel, slot_gate = dispatch_tables(idx_l, gates_l, E, C)  # (E, C)
+            send = x_l[sel].reshape(ep, E_loc, C, D)  # [j] = slots for shard j
+            my = jax.lax.axis_index(ep_axis)
+            # rolled[r] = block destined to shard (my + r) % ep; round 0 local
+            rolled = jnp.roll(send, -my, axis=0)
         layout = DispatchLayout("padded", E_loc, capacity=C)
         outs = []
         for r in range(ep):
             if r == 0:
                 blk = rolled[0]
             else:
-                blk = jax.lax.ppermute(
-                    rolled[r], ep_axis, [(i, (i + r) % ep) for i in range(ep)]
-                )  # arrives from shard (my - r) % ep: its slots for my experts
+                with jax.named_scope("moe_dispatch"):
+                    blk = jax.lax.ppermute(
+                        rolled[r], ep_axis, [(i, (i + r) % ep) for i in range(ep)]
+                    )  # arrives from shard (my - r) % ep: its slots for my experts
             ye = expert_ffn(experts_l, blk[None], layout, use_kernel)[0]
             if r == 0:
                 outs.append(ye)
             else:
-                outs.append(jax.lax.ppermute(
-                    ye, ep_axis, [(i, (i - r) % ep) for i in range(ep)]
-                ))  # back to its source: my block processed by (my + r) % ep
-        # outs[r] holds results for global experts of shard (my + r) % ep;
-        # un-roll to expert-shard-major order matching ``sel``
-        ret = jnp.roll(jnp.stack(outs), my, axis=0).reshape(E, C, D)
-        ret = ret * slot_gate[..., None].astype(ret.dtype)
-        return jnp.zeros((T_loc, D), ret.dtype).at[
-            sel.reshape(E * C)
-        ].add(ret.reshape(E * C, D))
+                with jax.named_scope("moe_combine"):
+                    outs.append(jax.lax.ppermute(
+                        ye, ep_axis, [(i, (i - r) % ep) for i in range(ep)]
+                    ))  # back to its source: my block processed by (my + r) % ep
+        with jax.named_scope("moe_combine"):
+            # outs[r] holds results for global experts of shard (my + r) % ep;
+            # un-roll to expert-shard-major order matching ``sel``
+            ret = jnp.roll(jnp.stack(outs), my, axis=0).reshape(E, C, D)
+            ret = ret * slot_gate[..., None].astype(ret.dtype)
+            return jnp.zeros((T_loc, D), ret.dtype).at[
+                sel.reshape(E * C)
+            ].add(ret.reshape(E * C, D))

@@ -189,6 +189,11 @@ def expert_ffn(
     the weights up front (functionally identical, no byte savings) so every
     dispatcher keeps working under quantization.
     """
+    with jax.named_scope("moe_experts"):
+        return _expert_ffn(experts, xe, layout, use_kernel)
+
+
+def _expert_ffn(experts, xe: jax.Array, layout: DispatchLayout, use_kernel: bool) -> jax.Array:
     from repro.core.quant import dequantize_experts, is_quantized
 
     quant = is_quantized(experts)
@@ -262,6 +267,8 @@ class TokenDispatcher:
         idx: jax.Array,
         use_kernel: bool = False,
     ) -> jax.Array:
-        xe, state = self.dispatch(x, idx, gates)
+        with jax.named_scope("moe_dispatch"):
+            xe, state = self.dispatch(x, idx, gates)
         ye = expert_ffn(experts, xe, state.layout, use_kernel)
-        return self.combine(ye, state)
+        with jax.named_scope("moe_combine"):
+            return self.combine(ye, state)

@@ -122,9 +122,10 @@ class SortedDispatcher(TokenDispatcher):
         from repro.core.quant import is_quantized
         from repro.kernels import ops
 
-        token, slot, dest, gate_sorted, group_sizes = self._indices(
-            idx, gates, KERNEL_ROW_BLOCK
-        )
+        with jax.named_scope("moe_dispatch"):
+            token, slot, dest, gate_sorted, group_sizes = self._indices(
+                idx, gates, KERNEL_ROW_BLOCK
+            )
         if is_quantized(experts):
             return ops.grouped_gemm_fused_q8(
                 x,
@@ -150,10 +151,14 @@ class SortedDispatcher(TokenDispatcher):
         use_kernel: bool = False,
     ) -> jax.Array:
         if use_kernel and getattr(self.moe, "fused_dispatch", False):
-            return self._apply_fused(experts, x, gates, idx)
+            # gather and combine run inside the kernel
+            with jax.named_scope("moe_experts"):
+                return self._apply_fused(experts, x, gates, idx)
         # the kernel tiles rows -> tile-aligned regions; XLA ragged_dot
         # consumes the compact buffer
         row_block = KERNEL_ROW_BLOCK if use_kernel else 1
-        xe, state = self.dispatch(x, idx, gates, row_block=row_block)
+        with jax.named_scope("moe_dispatch"):
+            xe, state = self.dispatch(x, idx, gates, row_block=row_block)
         ye = expert_ffn(experts, xe, state.layout, use_kernel)
-        return self.combine(ye, state)
+        with jax.named_scope("moe_combine"):
+            return self.combine(ye, state)

@@ -86,12 +86,13 @@ def moe_apply(
     B, S, D = x.shape
     T = B * S
     xf = x.reshape(T, D)
-    if moe.router_type == "expert_choice":
-        from repro.core.router import route_full
+    with jax.named_scope("moe_router"):
+        if moe.router_type == "expert_choice":
+            from repro.core.router import route_full
 
-        gates, idx, aux = route_full(moe, params["router"], xf)
-    else:
-        gates, idx, aux = route(moe, params["router"], xf, rng, train)
+            gates, idx, aux = route_full(moe, params["router"], xf)
+        else:
+            gates, idx, aux = route(moe, params["router"], xf, rng, train)
 
     dispatcher = get_dispatcher(cfg, moe, plan, T, B)
     out = dispatcher.apply(params["experts"], xf, gates, idx, use_kernel)

@@ -89,7 +89,8 @@ def forward(
     tokens = batch["tokens"]
     B, St = tokens.shape
     dtype = jnp.dtype(cfg.dtype)
-    x = embed_apply(params["embed"], tokens, dtype)
+    with jax.named_scope("embed"):
+        x = embed_apply(params["embed"], tokens, dtype)
     prefix = 0
     cross_ctx = None
     if cfg.family == "vlm":
@@ -108,10 +109,11 @@ def forward(
         cfg, plan, slots, params["stack"], x, positions, rng, train,
         cross_ctx=cross_ctx, use_kernel=use_kernel,
     )
-    x = norm_apply(params["final_norm"], x, cfg.norm_type, cfg.norm_eps)
-    if prefix:
-        x = x[:, prefix:]
-    logits = unembed_apply(params["embed"] if cfg.tie_embeddings else params["embed"], x)
+    with jax.named_scope("head_loss"):
+        x = norm_apply(params["final_norm"], x, cfg.norm_type, cfg.norm_eps)
+        if prefix:
+            x = x[:, prefix:]
+        logits = unembed_apply(params["embed"] if cfg.tie_embeddings else params["embed"], x)
     if plan is not None:
         logits = plan.constrain(logits, "fold_batch", None, "vocab")
     return logits, aux
@@ -125,9 +127,15 @@ def loss_fn(
     rng: Optional[jax.Array] = None,
     use_kernel: bool = False,
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    logits, aux = forward(cfg, plan, params, batch, rng, train=True, use_kernel=use_kernel)
-    ce = cross_entropy(logits, batch["labels"], cfg.vocab_size)
-    loss = ce + sum(aux.values())
+    # one scope around the whole loss, so that the layer scopes inside come
+    # out as plain op-name segments under jvp( and transpose( (a scope at
+    # the top of a differentiated function is folded into the transform's
+    # own segment, and its transposed ops lose it)
+    with jax.named_scope("model"):
+        logits, aux = forward(cfg, plan, params, batch, rng, train=True, use_kernel=use_kernel)
+        with jax.named_scope("head_loss"):
+            ce = cross_entropy(logits, batch["labels"], cfg.vocab_size)
+        loss = ce + sum(aux.values())
     metrics = {"loss": loss, "ce": ce, **aux}
     return loss, metrics
 

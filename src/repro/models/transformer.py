@@ -103,7 +103,8 @@ def block_apply(
     return_cache: bool = False,
 ) -> Tuple[jax.Array, Optional[Dict[str, jax.Array]], Dict[str, jax.Array]]:
     aux: Dict[str, jax.Array] = {}
-    h = norm_apply(params["norm1"], x, cfg.norm_type, cfg.norm_eps)
+    with jax.named_scope("norm"):
+        h = norm_apply(params["norm1"], x, cfg.norm_type, cfg.norm_eps)
     new_cache: Dict[str, jax.Array] = {}
     if spec.mixer == "ssm":
         mix, c = ssm_apply(
@@ -113,24 +114,26 @@ def block_apply(
         if c is not None:
             new_cache["ssm"] = c
     else:
-        if spec.mixer == "attn" and not spec.causal:
-            mix, c = gqa_apply(
-                cfg, plan, params["mixer"], h, positions, causal=False,
-                use_kernel=use_kernel,
-            )
-        else:
-            mix, c = attention_apply(
-                cfg, plan, params["mixer"], h, positions,
-                cache.get("attn") if cache else None, cache_view,
-                return_kv=return_cache, use_kernel=use_kernel,
-            )
+        with jax.named_scope("attention"):
+            if spec.mixer == "attn" and not spec.causal:
+                mix, c = gqa_apply(
+                    cfg, plan, params["mixer"], h, positions, causal=False,
+                    use_kernel=use_kernel,
+                )
+            else:
+                mix, c = attention_apply(
+                    cfg, plan, params["mixer"], h, positions,
+                    cache.get("attn") if cache else None, cache_view,
+                    return_kv=return_cache, use_kernel=use_kernel,
+                )
         if c is not None:
             new_cache["attn"] = c
     x = x + mix
 
     if spec.cross_attn:
         assert cross_ctx is not None or (cache is not None and "cross" in cache)
-        h = norm_apply(params["norm_cross"], x, cfg.norm_type, cfg.norm_eps)
+        with jax.named_scope("norm"):
+            h = norm_apply(params["norm_cross"], x, cfg.norm_type, cfg.norm_eps)
         if cache is not None and "cross" in cache:
             ck, cv, cp = cache["cross"]["k"], cache["cross"]["v"], cache_view["enc_pos"]
             new_cache["cross"] = cache["cross"]
@@ -144,7 +147,8 @@ def block_apply(
         x = x + cx
 
     if spec.ffn != "none":
-        h = norm_apply(params["norm2"], x, cfg.norm_type, cfg.norm_eps)
+        with jax.named_scope("norm"):
+            h = norm_apply(params["norm2"], x, cfg.norm_type, cfg.norm_eps)
         if spec.ffn == "moe":
             y, aux = moe_apply(
                 cfg, cfg.moe, plan, params["ffn"], h, rng, train, use_kernel
@@ -226,7 +230,8 @@ def stack_apply(
         body = jax.checkpoint(body, prevent_cse=False)
 
     aux0 = {k: jnp.zeros((), jnp.float32) for k in AUX_KEYS}
-    (x, aux), new_cache = jax.lax.scan(body, (x, aux0), (params, cache, keys))
+    with jax.named_scope("layer_scan"):
+        (x, aux), new_cache = jax.lax.scan(body, (x, aux0), (params, cache, keys))
     return x, new_cache, aux
 
 

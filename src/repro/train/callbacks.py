@@ -1,6 +1,6 @@
 """Composable recipe-loop callbacks. The ``Trainer`` loop is deliberately
 tiny — fetch batch, run the jitted state step — and everything else
-(logging/MFU, periodic eval, checkpointing) hangs off this interface, so the
+(logging, periodic eval, checkpointing) hangs off this interface, so the
 train / dryrun / upcycle launchers share one runtime and tests can inject
 instrumented callbacks.
 
@@ -29,7 +29,7 @@ class Callback:
 
 
 class LoggingCallback(Callback):
-    """History records + throughput/MFU accounting.
+    """History records + step-time accounting.
 
     Step 1 of a fresh process pays jit compilation; folding it into a
     running average deflates reported steady-state throughput, so timing is
@@ -44,18 +44,6 @@ class LoggingCallback(Callback):
 
     def on_run_begin(self, trainer):
         self.durations = []
-        n_chips = 1 if trainer.plan is None else trainer.plan.mesh.devices.size
-        tokens_per_step = trainer.tcfg.global_batch * trainer.tcfg.seq_len
-        # MFU accounting: 3x = fwd + bwd (2x) model FLOPs, the paper's (and
-        # Megatron's) convention. Recompute FLOPs are EXCLUDED: the Pallas
-        # backward re-derives the SwiGLU gate/up projections and the flash
-        # probability blocks instead of saving them, so the kernel path does
-        # strictly more arithmetic than 3x — reported MFU is therefore a
-        # slight *under*-estimate there, never inflated by recompute.
-        self._flops_per_step = (
-            3 * trainer.cfg.flops_per_token(trainer.tcfg.seq_len) * tokens_per_step
-        )
-        self._n_chips = n_chips
 
     def _steady(self) -> float:
         d = self.durations
@@ -74,7 +62,6 @@ class LoggingCallback(Callback):
             "sec_per_step": steady,
             "ms_per_step_steady": steady * 1e3,
             "wall_total_s": float(np.sum(self.durations)),
-            "model_tflops_per_sec": self._flops_per_step / steady / 1e12 / self._n_chips,
         }
         trainer.history.append(rec)
         self.log(

@@ -1,6 +1,6 @@
 """Training recipe loop: jitted TrainState step (loss + grad + clip +
 AdamW/ZeRO-1 + schedule) driven by a tiny loop with composable callbacks
-(logging/MFU, periodic eval, full-state async checkpointing — see
+(logging, periodic eval, full-state async checkpointing — see
 ``train/callbacks.py``). The state itself (params, optimizer, step, RNG) is
 the explicit :class:`repro.train.state.TrainState` pytree, so checkpointing
 and exact resume are properties of the state, not of this loop.
@@ -97,36 +97,40 @@ def make_train_step(
         else:
             (_, metrics), grads = grad_of(params, batch, rng)
         if guard is not None:
-            grads = jax.tree.map(
-                lambda g: g * guard["grad_scale"].astype(g.dtype), grads
-            )
+            with jax.named_scope("anomaly_guard"):
+                grads = jax.tree.map(
+                    lambda g: g * guard["grad_scale"].astype(g.dtype), grads
+                )
         lr = cosine_schedule(
             opt_state.step, tcfg.lr, tcfg.lr_min, tcfg.warmup_steps, tcfg.total_steps
         )
-        new_params, new_opt = adamw_update(tcfg, grads, opt_state, lr)
+        with jax.named_scope("adamw"):
+            new_params, new_opt = adamw_update(tcfg, grads, opt_state, lr)
         # adamw_update types new params from the grads; microbatch-accumulated
         # grads are fp32, so pin the compute dtype back to the params' (no-op
         # when m_eff == 1) — otherwise step 2 retraces with fp32 params
         new_params = jax.tree.map(
             lambda n, p: n.astype(p.dtype), new_params, params
         )
-        gnorm = jnp.sqrt(
-            sum(jnp.sum(jnp.square(g.astype(jnp.float32))) for g in jax.tree.leaves(grads))
-        )
+        with jax.named_scope("grad_norm"):
+            gnorm = jnp.sqrt(
+                sum(jnp.sum(jnp.square(g.astype(jnp.float32))) for g in jax.tree.leaves(grads))
+            )
         metrics = {**metrics, "lr": lr, "grad_norm": gnorm}
         if guard is not None:
-            loss_obs = metrics["loss"] + guard["loss_shift"]
-            bad = (
-                ~jnp.isfinite(loss_obs)
-                | ~jnp.isfinite(gnorm)
-                | (loss_obs > guard["loss_ceiling"])
-            )
-            new_params = jax.tree.map(
-                lambda old, new: jnp.where(bad, old, new), params, new_params
-            )
-            new_opt = jax.tree.map(
-                lambda old, new: jnp.where(bad, old, new), opt_state, new_opt
-            )
+            with jax.named_scope("anomaly_guard"):
+                loss_obs = metrics["loss"] + guard["loss_shift"]
+                bad = (
+                    ~jnp.isfinite(loss_obs)
+                    | ~jnp.isfinite(gnorm)
+                    | (loss_obs > guard["loss_ceiling"])
+                )
+                new_params = jax.tree.map(
+                    lambda old, new: jnp.where(bad, old, new), params, new_params
+                )
+                new_opt = jax.tree.map(
+                    lambda old, new: jnp.where(bad, old, new), opt_state, new_opt
+                )
             metrics = {
                 **metrics, "loss": loss_obs, "skipped": bad.astype(jnp.float32)
             }
@@ -196,6 +200,9 @@ class Trainer:
         # an optional hung-step watchdog (None = disabled)
         self.loss_ceiling = float("inf")
         self.step_timeout_s = step_timeout_s
+        # loop iterations run so far: the step number of the profiler's
+        # ``repro.train.step`` spans (the global step is on the device)
+        self._iterations = 0
 
     # seed-era attribute access (tests, examples, benchmarks read these)
     @property
@@ -244,8 +251,25 @@ class Trainer:
         for cb in cbs:
             cb.on_run_begin(self)
         for _ in range(steps):
-            t0 = time.perf_counter()
+            self._iterations += 1
+            with jax.profiler.StepTraceAnnotation(
+                "repro.train.step", step_num=self._iterations
+            ):
+                self._step(cbs)
+        for cb in cbs:
+            cb.on_run_end(self)
+        return {"history": self.history}
+
+    def _step(self, cbs: Sequence[Callback]) -> None:
+        """One loop iteration. Each part runs under a ``repro.train.*``
+        profiler span (about a microsecond each when no profiler runs), so a
+        trace puts the device's idle time down to the host work that held
+        it: the batch upload, the launch, the wait on the device, the step
+        read-back and the callbacks."""
+        t0 = time.perf_counter()
+        with jax.profiler.TraceAnnotation("repro.train.input"):
             batch = {k: jnp.asarray(v) for k, v in next(self.data_iter).items()}
+        with jax.profiler.TraceAnnotation("repro.train.launch"):
             guard = {
                 "loss_ceiling": jnp.float32(self.loss_ceiling),
                 "grad_scale": jnp.float32(1.0),
@@ -263,22 +287,22 @@ class Trainer:
                         )
                     )
             self.state, metrics = self.step_fn(self.state, batch, guard)
-            # sync on the (tiny) metrics so per-step wall times are honest;
-            # the big state buffers stay on device and the checkpoint
-            # writer thread still overlaps subsequent steps
+        # sync on the (tiny) metrics so per-step wall times are honest;
+        # the big state buffers stay on device and the checkpoint
+        # writer thread still overlaps subsequent steps
+        with jax.profiler.TraceAnnotation("repro.train.wait"):
             jax.block_until_ready(metrics["loss"])
-            dt = time.perf_counter() - t0
-            if self.step_timeout_s is not None and dt > self.step_timeout_s:
-                raise HangError(
-                    f"train step exceeded its {self.step_timeout_s:.3f}s wall "
-                    f"budget ({dt:.3f}s) — hung collective or wedged host"
-                )
+        dt = time.perf_counter() - t0
+        if self.step_timeout_s is not None and dt > self.step_timeout_s:
+            raise HangError(
+                f"train step exceeded its {self.step_timeout_s:.3f}s wall "
+                f"budget ({dt:.3f}s) — hung collective or wedged host"
+            )
+        with jax.profiler.TraceAnnotation("repro.train.readback"):
             step_no = int(jax.device_get(self.state.step))
+        with jax.profiler.TraceAnnotation("repro.train.callbacks"):
             for cb in cbs:
                 cb.on_step_end(self, step_no, metrics, dt)
-        for cb in cbs:
-            cb.on_run_end(self)
-        return {"history": self.history}
 
     def eval_loss(self, batches: int = 8, seed: int = 999, data_seed: Optional[int] = None) -> float:
         """Held-out loss: SAME blend/language (data_seed, default the train

@@ -288,8 +288,7 @@ def test_history_timing_excludes_warmup():
     tr = Trainer(cfg, tcfg, data_iter=it)
     tr.run(4, log=lambda *_: None)
     last = tr.history[-1]
-    for key in ("ms_per_step_steady", "wall_total_s", "sec_per_step",
-                "model_tflops_per_sec"):
+    for key in ("ms_per_step_steady", "wall_total_s", "sec_per_step"):
         assert key in last and last[key] > 0, key
     # step 1 pays jit compilation; the steady figure must exclude it
     step1_s = tr.history[0]["wall_total_s"]
