@@ -1,7 +1,8 @@
 """Roofline-driven Pallas tile autotuner with a persistent cache.
 
 The static ``_pick`` heuristics in ``expert_gemm`` / ``flash_attention`` /
-``paged_attention`` choose one tile size per dimension from a fixed default.
+``paged_attention`` choose one tile size per dimension from a fixed default
+(flash attention's from the shape, ``flash_tiles``).
 That is robust but leaves performance on the table when the problem shape
 makes a different lane split cheaper (e.g. small-D experts where a wider F
 tile amortizes weight re-reads, or short KV pages where a sub-page block

@@ -207,24 +207,22 @@ def grouped_gemm_xla(xs, w_gate, w_up, w_down, group_sizes):
     return jax.lax.ragged_dot(h, w_down, group_sizes)
 
 
-def _fa_cost(B: int, H: int, KV: int, Sq: int, Sk: int, d: int):
-    """Flash-attention traffic model at (bq, bk): q/out read+written once
-    per head, K/V re-read once per query tile, score tile in fp32 VMEM."""
+def _fa_cost(B: int, H: int, Sq: int, Sk: int, d: int, causal: bool,
+             window: Optional[int], it: int):
+    """Flash-attention forward model at (bq, bk), over the live-tile
+    schedule that runs (``flash_schedule``): each grid step of a query head
+    multiplies one (bq, bk) tile and fetches one K and one V block; q is
+    read and out written once."""
 
     def cost(blocks):
         bq, bk = blocks
-        nq, nk = Sq // bq, Sk // bk
+        sched = _fa.flash_schedule(Sq, Sk, bq, bk, causal, window)
+        steps = B * H * sched.steps["fwd"]
         return {
-            "flops": 4.0 * B * H * Sq * Sk * d,
-            "bytes": (
-                B * H * Sq * d * 2.0 * 2.0      # q in, out
-                + B * KV * nq * Sk * d * 2.0 * 2.0  # k+v per q tile
-            ),
-            "steps": float(B * H * nq * nk),
-            "vmem_bytes": (
-                bq * d * 2.0 + 2.0 * bk * d * 2.0 + bq * d * 4.0
-                + bq * bk * 4.0 + 2.0 * bq * 4.0
-            ),
+            "flops": 4.0 * B * H * sched.live * bq * bk * d,
+            "bytes": B * H * Sq * d * 2.0 * it + steps * 2.0 * bk * d * it,
+            "steps": float(steps),
+            "vmem_bytes": _fa.vmem_bytes(bq, bk, d, it),
         }
 
     return cost
@@ -232,18 +230,22 @@ def _fa_cost(B: int, H: int, KV: int, Sq: int, Sk: int, d: int):
 
 def flash_attention(
     q, k, v, causal: bool = True, window: Optional[int] = None,
-    scale: Optional[float] = None, blocks=_fa.DEFAULT_BLOCKS,
+    scale: Optional[float] = None, blocks=None,
 ):
+    """``blocks`` bounds the tiles; None takes ``flash_tiles`` of the
+    shape. With tuning on, the tuner scores candidates by ``_fa_cost``."""
     B, Sq, H, d = q.shape
-    Sk, KV = k.shape[1], k.shape[2]
+    Sk = k.shape[1]
+    it = q.dtype.itemsize
     blocks = _at.get_blocks(
         "flash_attention",
-        _at.make_key("flash_attention", D=d, itemsize=q.dtype.itemsize,
-                     extra=f"Sq{Sq}xSk{Sk}"),
-        _fa._tiling(Sq, Sk, blocks),
+        _at.make_key("flash_attention", D=d, itemsize=it,
+                     extra=f"Sq{Sq}xSk{Sk}c{int(causal)}w{window}"),
+        _fa.flash_tiles(Sq, Sk, d, it) if blocks is None
+        else _fa._tiling(Sq, Sk, blocks),
         dims=(Sq, Sk),
         aligns=(8, 8),
-        cost=_fa_cost(B, H, KV, Sq, Sk, d),
+        cost=_fa_cost(B, H, Sq, Sk, d, causal, window, it),
     )
     return _fa.flash_attention(
         q, k, v, causal=causal, window=window, scale=scale, blocks=blocks,

@@ -114,24 +114,35 @@ def test_grouped_gemm_matches_padded_expert_gemm(rng):
         off += gs[e]
 
 
-FA_CASES = [  # (B, Sq, Sk, H, KV, d, causal, window)
-    (2, 64, 64, 4, 2, 32, True, None),
-    (1, 32, 128, 4, 4, 64, True, None),  # decode-ish: Sq < Sk, right-aligned
-    (2, 128, 128, 8, 2, 32, True, 16),  # sliding window
-    (1, 64, 64, 2, 2, 16, False, None),  # encoder (non-causal)
-    (2, 1, 256, 4, 1, 64, True, None),  # single-token decode, MQA
-    (1, 256, 256, 4, 4, 128, True, None),  # head_dim 128 (TPU-native)
+# explicit small blocks: several q and k tiles, dead ones among them
+FA_TILED_CASES = [  # (B, Sq, Sk, H, KV, d, causal, window, blocks)
+    (1, 512, 512, 2, 2, 32, True, None, (128, 128)),  # causal, 4 x 4 tiles
+    (1, 128, 512, 2, 1, 32, True, None, (32, 128)),  # right-aligned Sq < Sk
+    (1, 256, 256, 2, 2, 32, True, 24, (64, 64)),  # window narrower than a tile
+    (1, 256, 256, 2, 2, 32, True, 96, (64, 64)),  # window wider than a tile
+    (2, 256, 256, 4, 2, 32, True, None, (64, 128)),  # GQA G 2, bq != bk
+    (1, 64, 256, 2, 1, 32, True, 16, (32, 64)),  # k-blocks no query sees
 ]
+
+
+FA_CASES = [  # (B, Sq, Sk, H, KV, d, causal, window, blocks)
+    (2, 64, 64, 4, 2, 32, True, None, None),
+    (1, 32, 128, 4, 4, 64, True, None, None),  # decode-ish: Sq < Sk, right-aligned
+    (2, 128, 128, 8, 2, 32, True, 16, None),  # sliding window
+    (1, 64, 64, 2, 2, 16, False, None, None),  # encoder (non-causal)
+    (2, 1, 256, 4, 1, 64, True, None, None),  # single-token decode, MQA
+    (1, 256, 256, 4, 4, 128, True, None, None),  # head_dim 128 (TPU-native)
+] + FA_TILED_CASES
 
 
 @pytest.mark.parametrize("case", FA_CASES)
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_flash_attention(rng, case, dtype):
-    B, Sq, Sk, H, KV, d, causal, window = case
+    B, Sq, Sk, H, KV, d, causal, window, blocks = case
     q = jnp.asarray(rng.standard_normal((B, Sq, H, d)), dtype) * 0.3
     k = jnp.asarray(rng.standard_normal((B, Sk, KV, d)), dtype) * 0.3
     v = jnp.asarray(rng.standard_normal((B, Sk, KV, d)), dtype) * 0.3
-    y = flash_attention(q, k, v, causal=causal, window=window)
+    y = flash_attention(q, k, v, causal=causal, window=window, blocks=blocks)
     kb, vb = jnp.repeat(k, H // KV, 2), jnp.repeat(v, H // KV, 2)
     yr = flash_attention_ref(q, kb, vb, causal=causal, window=window)
     atol = 1e-5 if dtype == jnp.float32 else 2e-2
@@ -280,12 +291,12 @@ def test_grouped_gemm_backward_saves_no_nf_residual(rng):
     ), shapes
 
 
-FA_GRAD_CASES = [  # (B, Sq, Sk, H, KV, d, causal, window)
-    (2, 64, 64, 4, 2, 32, True, None),  # GQA causal
-    (1, 32, 128, 4, 4, 64, True, None),  # right-aligned Sq < Sk
-    (2, 128, 128, 8, 2, 32, True, 16),  # sliding window
-    (1, 64, 64, 2, 2, 16, False, None),  # non-causal (encoder)
-]
+FA_GRAD_CASES = [  # (B, Sq, Sk, H, KV, d, causal, window, blocks)
+    (2, 64, 64, 4, 2, 32, True, None, None),  # GQA causal
+    (1, 32, 128, 4, 4, 64, True, None, None),  # right-aligned Sq < Sk
+    (2, 128, 128, 8, 2, 32, True, 16, None),  # sliding window
+    (1, 64, 64, 2, 2, 16, False, None, None),  # non-causal (encoder)
+] + FA_TILED_CASES
 
 
 @pytest.mark.parametrize("case", FA_GRAD_CASES)
@@ -293,7 +304,7 @@ FA_GRAD_CASES = [  # (B, Sq, Sk, H, KV, d, causal, window)
 def test_flash_attention_grad_parity(rng, case, dtype):
     """jax.grad through the two-pass flash backward (p recomputed from the
     saved logsumexp) == grad of the dense softmax reference."""
-    B, Sq, Sk, H, KV, d, causal, window = case
+    B, Sq, Sk, H, KV, d, causal, window, blocks = case
     q = jnp.asarray(rng.standard_normal((B, Sq, H, d)), dtype) * 0.3
     k = jnp.asarray(rng.standard_normal((B, Sk, KV, d)), dtype) * 0.3
     v = jnp.asarray(rng.standard_normal((B, Sk, KV, d)), dtype) * 0.3
@@ -301,7 +312,8 @@ def test_flash_attention_grad_parity(rng, case, dtype):
     G = H // KV
 
     def loss_k(q, k, v):
-        return jnp.sum(flash_attention(q, k, v, causal=causal, window=window) * r)
+        out = flash_attention(q, k, v, causal=causal, window=window, blocks=blocks)
+        return jnp.sum(out * r)
 
     def loss_r(q, k, v):
         kb, vb = jnp.repeat(k, G, 2), jnp.repeat(v, G, 2)
@@ -362,6 +374,98 @@ def test_pick_lane_alignment():
     assert _pick(128, 320, align=8) == 80
     assert _pick(128, 1, align=8) == 1
     assert _pick(128, 282, align=8) == 94  # no 8-divisor: largest divisor
+
+
+SCHEDULE_CASES = [  # (Sq, Sk, bq, bk, causal, window)
+    (512, 512, 128, 128, True, None),  # causal
+    (4096, 4096, 512, 512, True, None),  # the train cell's shape
+    (256, 256, 64, 64, True, 24),  # window narrower than a tile
+    (256, 256, 64, 32, True, 96),  # window wider than a tile
+    (128, 512, 32, 128, True, None),  # right-aligned Sq < Sk
+    (64, 256, 32, 64, True, 16),  # right-aligned window: dead k-blocks
+    (256, 256, 64, 64, False, 40),  # window alone
+    (256, 512, 64, 128, False, None),  # non-causal: the full grid
+]
+
+
+@pytest.mark.parametrize("case", SCHEDULE_CASES)
+def test_flash_schedule_walks_exactly_the_live_tiles(case):
+    """The schedule's computed tiles are the tiles a brute-force scan of the
+    dense mask finds any unmasked entry in, unmasked only where every entry
+    is; each q-block (k-block) is initialised on its first live tile and
+    written on its last, in walk order; a row with no live tile still
+    initialises and writes once."""
+    from repro.kernels.flash_attention import EDGE, FULL, INIT, WRITE, flash_schedule
+
+    Sq, Sk, bq, bk, causal, window = case
+    qpos = np.arange(Sq)[:, None] + Sk - Sq
+    kpos = np.arange(Sk)[None, :]
+    mask = np.ones((Sq, Sk), bool)
+    if causal:
+        mask &= kpos <= qpos
+    if window is not None:
+        mask &= kpos > qpos - window
+    nq, nk = Sq // bq, Sk // bk
+    tiles = mask.reshape(nq, bq, nk, bk).transpose(0, 2, 1, 3)
+    live = {(qi, ki) for qi in range(nq) for ki in range(nk) if tiles[qi, ki].any()}
+    full = {(qi, ki) for qi in range(nq) for ki in range(nk) if tiles[qi, ki].all()}
+
+    s = flash_schedule(Sq, Sk, bq, bk, causal, window)
+    assert (s.nq, s.nk, s.full) == (nq, nk, nq * nk)
+    assert s.live == len(live)
+    for walk, row in ((s.q_walk, 0), (s.k_walk, 1)):
+        pairs = [(qi, ki) for qi, ki, f in walk if f & (FULL | EDGE)]
+        assert set(pairs) == live and len(pairs) == len(live)
+        assert {(qi, ki) for qi, ki, f in walk if f & FULL} == full
+        assert not any(f & FULL and f & EDGE for *_, f in walk)
+        keys = [(t[row], t[1 - row]) for t in walk]
+        assert keys == sorted(keys)  # q-major, resp. k-major
+        for r in range(nq if row == 0 else nk):
+            steps = [t for t in walk if t[row] == r]
+            assert steps[0][2] & INIT and steps[-1][2] & WRITE
+            assert sum(bool(t[2] & INIT) for t in steps) == 1
+            assert sum(bool(t[2] & WRITE) for t in steps) == 1
+            mine = sorted(p[1 - row] for p in live if p[row] == r)
+            if mine:
+                assert [t[1 - row] for t in steps] == mine
+            else:
+                assert len(steps) == 1 and not steps[0][2] & (FULL | EDGE)
+    assert s.steps == {"fwd": len(s.q_walk), "dq": len(s.q_walk),
+                       "dkv": len(s.k_walk)}
+    if not causal and window is None:
+        assert s.live == s.full == s.steps["fwd"] == s.steps["dkv"]
+
+
+def test_flash_schedule_per_head_walk():
+    """dk/dv walk each k-block's tiles once per query head of the group,
+    initialising on the first head and writing on the last."""
+    from repro.kernels.flash_attention import INIT, WRITE, _per_head, flash_schedule
+
+    s = flash_schedule(256, 256, 64, 64, True, None)
+    walk = _per_head(s.k_walk, 3)
+    assert len(walk) == 3 * len(s.k_walk)
+    for ki in range(s.nk):
+        steps = [t for t in walk if t[2] == ki]
+        assert [t[0] for t in steps] == sorted(t[0] for t in steps)
+        assert [bool(t[3] & INIT) for t in steps] == [True] + [False] * (len(steps) - 1)
+        assert [bool(t[3] & WRITE) for t in steps] == [False] * (len(steps) - 1) + [True]
+
+
+def test_flash_tiles_from_shape():
+    """Tiles are the largest divisors up to 512, the whole dim for short
+    sequences, and shrink until the backward's VMEM working set fits."""
+    from repro.kernels.flash_attention import VMEM_BUDGET, flash_schedule, flash_tiles, vmem_bytes
+
+    assert flash_tiles(4096, 4096, 64) == (512, 512)
+    assert flash_schedule(4096, 4096, 512, 512, True, None).live == 36
+    assert flash_tiles(4096, 4096, 128) == (512, 512)
+    assert flash_tiles(64, 64, 32) == (64, 64)
+    assert flash_tiles(1, 256, 64) == (1, 256)
+    assert flash_tiles(1536, 1536, 64) == (512, 512)
+    assert flash_tiles(640, 640, 64) == (320, 320)
+    bq, bk = flash_tiles(4096, 4096, 1024)
+    assert vmem_bytes(bq, bk, 1024, 2) <= VMEM_BUDGET < vmem_bytes(512, 512, 1024, 2)
+    assert 4096 % bq == 0 and 4096 % bk == 0 and bq % 8 == 0 and bk % 8 == 0
 
 
 def test_flash_matches_model_blockwise_path(rng):

@@ -77,12 +77,14 @@ def _compile(fn, one_chip, *shapes):
     return compiled
 
 
-@pytest.mark.parametrize("heads", [(H, KV, HD), (32, 32, 64)],
-                         ids=["e8t2", "stablelm"])
+@pytest.mark.parametrize("heads", [(H, KV, HD, 2048), (32, 32, 64, 2048),
+                                   (32, 32, 64, 4096)],
+                         ids=["e8t2", "stablelm", "stablelm-s4096"])
 @pytest.mark.parametrize("direction", ["fwd", "bwd"])
 def test_flash_attention_compiles(one_chip, heads, direction):
-    h, kv, d = heads
-    S = 2048
+    """Shape-derived tiles and the live-tile schedule compile; S 4096 with
+    32 heads of 64 is the train cell's shape, tiles (512, 512)."""
+    h, kv, d, S = heads
 
     def fwd(q, k, v):
         return fa.flash_attention(q, k, v, causal=True, interpret=False)
